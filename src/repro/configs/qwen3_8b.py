@@ -7,6 +7,19 @@ CONFIG = ModelConfig(
     n_kv_heads=8, d_ff=12288, vocab=151936, head_dim=128, qk_norm=True,
     rope_theta=1e6)
 
+#: The deployment REDUCED stands for: 8 chips split every layer's
+#: vocabulary rows (embedding and lm_head) 8 ways, and the 36 layers run as
+#: pipeline stages of 2 layers each.  One chip's share is 2 layers and
+#: 151936 / 8 = 18992 vocabulary rows; every width is as published.  QFT
+#: holds ~20 B/param (f32 teacher, student, grads, Adam m/v), so this share
+#: (542 M params) needs ~11 GB of a v5e chip's 16 GB before activations.
+DEPLOYMENT = ("8 chips share each layer's vocabulary rows; 2-layer "
+              "pipeline stages")
+reduced = {"n_layers": 2, "vocab": CONFIG.vocab // 8}
+
+# vocab_padded reset to 0 so __post_init__ re-derives it for the cut vocab
+REDUCED = dataclasses.replace(CONFIG, **reduced, vocab_padded=0)
+
 # padded fields reset to 0 so __post_init__ re-derives them at SMOKE
 # scale (dataclasses.replace would otherwise inherit the full-size
 # vocab/head padding -- a 150k-row embedding under a 512 vocab)

@@ -58,8 +58,15 @@ def get_module(arch: str):
     return importlib.import_module(f"repro.configs.{_MODULES[arch]}")
 
 
-def get_config(arch: str, smoke: bool = False):
+def get_config(arch: str, smoke: bool = False, reduced: bool = False):
+    """``smoke``: the CPU-sized test preset.  ``reduced``: one chip's share
+    of a stated deployment at published widths (the module's ``REDUCED``,
+    with the cut keys in ``reduced`` and the deployment in ``DEPLOYMENT``)."""
     m = get_module(arch)
+    if reduced:
+        if not hasattr(m, "REDUCED"):
+            raise ValueError(f"{arch} has no one-chip REDUCED config")
+        return m.REDUCED
     return m.SMOKE if smoke else m.CONFIG
 
 

@@ -10,7 +10,7 @@ streams the cache in [bk]-sized KV blocks with an online softmax instead:
   KV block; the GQA query group [G, hd] for that head stays VMEM-resident
   across the KV grid dimension (m/l/acc scratch, the flash pattern of
   kernels/flash_attention.py);
-- each slot's valid prefix length rides in as a [slots, 1] int32 operand;
+- each slot's valid prefix length rides in as a [slots] int32 SMEM operand;
   the in-block mask is ``block_start + lane < length``;
 - blocks entirely past a slot's length are *skipped* via ``pl.when`` — a
   slot at pos 17 touches one block of a 4096-deep cache instead of all 32.
@@ -45,6 +45,9 @@ from jax.experimental.pallas import tpu as pltpu
 from .quant_matmul import default_interpret
 
 _NEG = -1e30
+#: f32 operands stay f32 on the MXU (a TPU's default f32 dot rounds them to
+#: bf16), so the kernel agrees with its f32 reference on the chip too
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def decode_tiles_ok(max_len: int, bk: int = 128) -> bool:
@@ -57,25 +60,23 @@ def decode_tiles_ok(max_len: int, bk: int = 128) -> bool:
     return max_len % bk == 0
 
 
-def _fd_kernel(len_ref, q_ref, k_ref, v_ref, *rest, bk: int, n_k: int,
-               scale: float, quantized: bool):
+def _fd_kernel(len_ref, *refs, bk: int, n_k: int, scale: float,
+               quantized: bool):
     """One (slot, kv_head, kv_block) grid step.
 
-    len_ref: [1, 1]        int32 valid-prefix length of this slot (>= 1)
+    len_ref: [S]           int32 valid-prefix lengths, in SMEM (>= 1)
+    quantized → two [S, Hkv] f32 SMEM refs follow: the K and V dequant
+    scales, read at this program's (slot, head).
     q_ref:   [1, 1, G, hd] the slot's query group for this KV head
-    k_ref:   [1, bk, 1, hd]
-    v_ref:   [1, bk, 1, hd]
-    quantized → two extra [1, 1] f32 refs lead ``rest``: this (slot, head)'s
-    K and V dequant scales.
+    k_ref:   [1, bk, hd]   this head's columns of the [S, T, Hkv*hd] cache
+    v_ref:   [1, bk, hd]
     o_ref:   [1, 1, G, hd]
     m/l/acc: [G, 1] / [G, 1] / [G, hd] f32 VMEM online-softmax state
     """
     if quantized:
-        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
-    else:
-        ks_ref = vs_ref = None
-        o_ref, m_ref, l_ref, acc_ref = rest
-    j = pl.program_id(2)
+        ks_ref, vs_ref, *refs = refs
+    q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
+    s_id, h_id, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
@@ -83,15 +84,16 @@ def _fd_kernel(len_ref, q_ref, k_ref, v_ref, *rest, bk: int, n_k: int,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    length = len_ref[0, 0]
+    length = len_ref[s_id]
 
     def _block():
-        qscale = scale if not quantized else scale * ks_ref[0, 0]
+        qscale = scale if not quantized else scale * ks_ref[s_id, h_id]
         q = q_ref[0, 0].astype(jnp.float32) * qscale      # [G, hd]
-        k = k_ref[0, :, 0].astype(jnp.float32)            # [bk, hd]
-        v = v_ref[0, :, 0].astype(jnp.float32)            # [bk, hd]
+        k = k_ref[0].astype(jnp.float32)                  # [bk, hd]
+        v = v_ref[0].astype(jnp.float32)                  # [bk, hd]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)  # [G, bk]
+                                preferred_element_type=jnp.float32,
+                                precision=_HIGHEST)               # [G, bk]
         cols = j * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(cols < length, s, _NEG)             # per-slot prefix
         m_prev = m_ref[...]                               # [G, 1]
@@ -101,7 +103,7 @@ def _fd_kernel(len_ref, q_ref, k_ref, v_ref, *rest, bk: int, n_k: int,
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, -1, keepdims=True)
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            preferred_element_type=jnp.float32, precision=_HIGHEST)
         m_ref[...] = m_new
 
     # fully-dead blocks (entirely past this slot's length) are skipped —
@@ -113,7 +115,7 @@ def _fd_kernel(len_ref, q_ref, k_ref, v_ref, *rest, bk: int, n_k: int,
     def _out():
         acc = acc_ref[...] / jnp.maximum(l_ref[...], 1e-20)
         if quantized:
-            acc = acc * vs_ref[0, 0]
+            acc = acc * vs_ref[s_id, h_id]
         o_ref[0, 0] = acc.astype(o_ref.dtype)
 
 
@@ -133,6 +135,10 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
        scales for an int8 cache (both or neither)
     → [S, Hkv, G, hd].
 
+    The cache is viewed as [S, T, Hkv*hd] (a free reshape), so one head's
+    KV block is a [bk, hd] tile with hd lane-aligned; lengths and scales
+    live in SMEM, read as scalars per program.
+
     ``decode_tiles_ok(T, bk)`` must hold; interpret=None auto-selects by
     backend (models/attention.py gates the call and falls back to the
     masked-XLA `_sdpa` / `_paged_sdpa` path otherwise).
@@ -145,17 +151,18 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     assert (k_scale is None) == (v_scale is None)
     n_k = T // bk
     grid = (S, Hkv, n_k)
-    in_specs = [
-        pl.BlockSpec((1, 1), lambda s, h, j: (s, 0)),
-        pl.BlockSpec((1, 1, G, hd), lambda s, h, j: (s, h, 0, 0)),
-        pl.BlockSpec((1, bk, 1, hd), lambda s, h, j: (s, j, h, 0)),
-        pl.BlockSpec((1, bk, 1, hd), lambda s, h, j: (s, j, h, 0)),
-    ]
-    operands = [lengths.astype(jnp.int32)[:, None], q, k, v]
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    in_specs = [smem]
+    operands = [lengths.astype(jnp.int32)]
     if quantized:
-        in_specs += [pl.BlockSpec((1, 1), lambda s, h, j: (s, h)),
-                     pl.BlockSpec((1, 1), lambda s, h, j: (s, h))]
+        in_specs += [smem, smem]
         operands += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
+    in_specs += [
+        pl.BlockSpec((1, 1, G, hd), lambda s, h, j: (s, h, 0, 0)),
+        pl.BlockSpec((1, bk, hd), lambda s, h, j: (s, j, h)),
+        pl.BlockSpec((1, bk, hd), lambda s, h, j: (s, j, h)),
+    ]
+    operands += [q, k.reshape(S, T, -1), v.reshape(S, T, -1)]
     return pl.pallas_call(
         functools.partial(_fd_kernel, bk=bk, n_k=n_k, scale=hd ** -0.5,
                           quantized=quantized),

@@ -46,7 +46,7 @@ from ..sharding.partition import (ShardingPolicy, batch_shardings,
 from ..train.steps import (make_decode_step, make_prefill_step,
                            make_train_step)  # noqa: E402
 from . import hlo_analysis as H  # noqa: E402
-from .mesh import make_production_mesh, mesh_context  # noqa: E402
+from .mesh import make_production_mesh  # noqa: E402
 
 RESULTS_DIR = pathlib.Path(__file__).resolve().parents[3] / \
     "benchmarks" / "results" / "dryrun"
@@ -219,7 +219,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool,
     set_runtime(act_spec=pol.dp)
     t0 = time.time()
     try:
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             # --- full-depth compile: shardability + memory + exact collectives
             fn, args, cfg = build_cell(arch, shape, mesh, pol, variant=variant)
             lowered = fn.lower(*args)

@@ -136,8 +136,6 @@ def collective_stats(hlo: str, n_devices: int) -> dict[str, Any]:
 
 def cost_summary(compiled) -> dict[str, float]:
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):   # jax ≤0.4.x: one dict per device kind
-        ca = ca[0] if ca else {}
     return {"flops": float(ca.get("flops", 0.0)),
             "bytes": float(ca.get("bytes accessed", 0.0))}
 

@@ -1,53 +1,36 @@
-"""Production mesh definition (assignment-mandated shapes).
+"""Mesh definitions: the assignment's production shapes and meshes built from
+the devices actually present.
 
 Functions, not module-level constants: importing this module never touches
-jax device state.  ``_make_mesh``/``mesh_context`` paper over jax API drift:
-``AxisType`` and ``jax.set_mesh`` only exist on newer jax; older versions
-get the plain (auto-sharding) equivalents.
+jax device state.  Every axis is ``AxisType.Auto`` (GSPMD propagation); enter
+a mesh with ``jax.set_mesh(mesh)`` so bare-``PartitionSpec`` sharding
+constraints (models.transformer.constrain_act) resolve against it.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
-def _make_mesh(shape, axes):
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:                      # older jax: Auto is the default
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
-
-
-def mesh_context(mesh):
-    """``with mesh_context(mesh):`` — an ambient mesh across jax versions.
-
-    ``jax.set_mesh`` where available; on older jax (≤0.4.x) fall back to
-    entering the ``Mesh`` itself as a context manager, which installs the
-    resource env that ``with_sharding_constraint(x, PartitionSpec(...))``
-    needs at trace time.  (The earlier nullcontext fallback left
-    ``models.transformer.constrain_act`` without an ambient mesh on jax
-    0.4.37 — every dryrun prefill/decode cell failed with "requires a
-    non-empty mesh" while NamedSharding-only paths happened to work.)"""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is None:
-        return mesh                        # Mesh.__enter__ sets the env
-    return set_mesh(mesh)
+def _mesh(shape, axes):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_host_mesh():
     """Single-device mesh for smoke tests / local runs."""
-    return _make_mesh((1, 1), ("data", "model"))
+    return _mesh((1, 1), ("data", "model"))
 
 
 def make_elastic_mesh(n_devices: int, model_parallel: int = 16):
-    """Largest (data, model) mesh from ``n_devices`` survivors (elastic
-    restarts, train/elastic.py). Drops stragglers that break divisibility."""
+    """Largest (data, model) mesh from ``n_devices`` present or surviving
+    devices (launch/train.py, elastic restarts in train/elastic.py).  Drops
+    stragglers that break divisibility."""
     model_parallel = min(model_parallel, n_devices)
     data = n_devices // model_parallel
-    return _make_mesh((data, model_parallel), ("data", "model"))
+    return _mesh((data, model_parallel), ("data", "model"))

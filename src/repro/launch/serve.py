@@ -24,6 +24,7 @@ from ..pipeline import STAGES, PipelineConfig, run_pipeline
 from ..serve.deploy import export_for_layers, kernel_route_check
 from ..serve.engine import Engine, Request, ServeConfig
 from ..train.checkpoint import CheckpointManager
+from .compile_cache import enable_compile_cache
 
 
 def restore_student(ckpt_dir: str, student):
@@ -86,6 +87,7 @@ def main() -> None:
                     help="print tokens as they are emitted (Engine.stream) "
                          "instead of waiting for full completions")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.arch in ("paper-cnn", "paper_cnn"):
         print("error: paper-cnn is a classifier — it has no token-serving "
               "engine; use `python -m repro quantize --config paper_cnn` "

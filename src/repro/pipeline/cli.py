@@ -21,6 +21,7 @@ import argparse
 import sys
 
 from ..configs import registry
+from ..launch.compile_cache import enable_compile_cache
 from .config import MODES, STAGES, PipelineConfig
 from .runner import run_pipeline
 
@@ -287,4 +288,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     sys.exit(main())

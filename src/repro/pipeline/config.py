@@ -46,6 +46,8 @@ class PipelineConfig:
     bits_overrides: tuple = ()        # ((path-glob, bits), ...) plan rows
     layout_overrides: tuple = ()      # ((path-glob, layout spec), ...)
     smoke: bool = True                # registry SMOKE config (CPU-sized)
+    reduced: bool = False             # registry REDUCED config: one chip's
+                                      # share at published widths
     steps: int = 60                   # QFT finetune steps (0 skips training)
     seed: int = 0
     cle: bool = False                 # CLE+QFT two-step (paper Fig. 8)
@@ -81,10 +83,14 @@ class PipelineConfig:
             QLayout.parse(self.w_layout)      # fail fast on bad CLI specs
         if self.stop_after is not None and self.stop_after not in STAGES:
             raise ValueError(f"stop_after must be one of {STAGES}")
+        if self.smoke and self.reduced:
+            raise ValueError("smoke and reduced select different configs; "
+                             "set one")
 
     # ------------------------------------------------------------ resolution
     def model_config(self):
-        return registry.get_config(self.arch, smoke=self.smoke)
+        return registry.get_config(self.arch, smoke=self.smoke,
+                                   reduced=self.reduced)
 
     def quant_config(self) -> QuantConfig:
         qcfg = deployment_oriented() if self.mode == "w4a8" else permissive()

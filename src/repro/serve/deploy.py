@@ -381,13 +381,16 @@ def kernel_route_check(exported: Params, plan: DeployPlan) -> dict | None:
     """Drive ONE exported linear through kernels.ops.qlinear_deployed under
     the plan and compare against the dequantized reference matmul.
 
-    Returns {path, pallas, max_err} — ``pallas`` says whether the Pallas
-    quant_matmul kernel actually ran (int8/unpacked exports take the
-    reference branch regardless of the plan), so the metric can't silently
-    report kernel parity that never exercised the kernel.  None if the
-    artifact has no matmul-shaped linear (e.g. conv-only models with no
+    Returns {path, pallas, max_err} — ``pallas`` says whether the traced
+    program of that call contains the Pallas quant_matmul kernel (int8 /
+    unpacked exports and odd shapes take the XLA reference branch
+    regardless of the plan), so the metric can't silently report kernel
+    parity that never exercised the kernel.  The reference matmul runs at
+    full f32 precision (a TPU's default f32 dot rounds to bf16).  None if
+    the artifact has no matmul-shaped linear (e.g. conv-only models with no
     packed fc).
     """
+    from ..analysis.jaxpr_checks import has_pallas_call
     from ..kernels.ops import pallas_tiles_ok, qlinear_deployed
     paths = find_exported_linears(exported)
     if not paths:
@@ -406,7 +409,7 @@ def kernel_route_check(exported: Params, plan: DeployPlan) -> dict | None:
         return ex
 
     def reaches_kernel(ex):
-        # packed + evenly-tiling shapes — what actually runs the kernel
+        # packed + evenly-tiling shapes — what routes to the kernel
         if ex["q"].dtype != jnp.uint8:
             return False
         n_groups = ex["s_wr"].shape[0] if ex["s_wr"].ndim == 2 else None
@@ -428,15 +431,20 @@ def kernel_route_check(exported: Params, plan: DeployPlan) -> dict | None:
     w = dof.dequantize_export(ex, jnp.float32,
                               packed=ex["q"].dtype == jnp.uint8)
     x = jax.random.normal(jax.random.PRNGKey(0), (M, w.shape[0]), jnp.float32)
-    y = qlinear_deployed(x, ex, plan=plan)
-    y_ref = x @ w
+
+    def route(x, ex):
+        return qlinear_deployed(x, ex, plan=plan)
+
+    y = route(x, ex)
+    with jax.default_matmul_precision("highest"):
+        y_ref = x @ w
     if "b" in ex:
         y_ref = y_ref + ex["b"]
     return {"path": dotted,
             "layout": (spec.layout if spec is not None
                        else str(plan.layout if plan.layout is not None
                                 else plan.qcfg.layout)),
-            "pallas": bool(plan.use_pallas and reaches_kernel(ex)),
+            "pallas": has_pallas_call(jax.make_jaxpr(route)(x, ex)),
             "max_err": float(jnp.max(jnp.abs(y - y_ref)))}
 
 

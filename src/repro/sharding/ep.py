@@ -30,7 +30,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from ..core import dof
 from ..core.plan import plan_view
@@ -139,8 +138,8 @@ def make_ep_moe(mesh: Mesh, cfg: ModelConfig, qcfg: QuantConfig | None,
         import functools
         body = functools.partial(local_moe, qcfg=qcfg_eff)
         p_specs = jax.tree_util.tree_map_with_path(pspec, p)
-        fn = shard_map(body, mesh=mesh, in_specs=(x_spec, p_specs),
-                       out_specs=x_spec, check_rep=False)
+        fn = jax.shard_map(body, mesh=mesh, in_specs=(x_spec, p_specs),
+                           out_specs=x_spec, check_vma=False)
         return fn(x, p)
 
     return moe_fn

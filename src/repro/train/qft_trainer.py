@@ -296,11 +296,16 @@ class QFTTrainer:
                                           grad_mask=grad_mask, plan=plan)
 
     # -------------------------------------------------------------- prepare
-    def prepare_student(self, key, calib_batches: Iterable[dict]) -> Params:
-        student = build_student(key, self.cfg, self.qcfg, self.teacher)
+    def prepare_student(self, key, calib_batches: Iterable[dict],
+                        teacher: Params | None = None) -> Params:
+        """Build, calibrate and MMSE-init the student.  ``teacher`` defaults
+        to the trainer's; pass it explicitly to jit this (a closed-over
+        teacher would be baked into the program as a constant)."""
+        teacher = self.teacher if teacher is None else teacher
+        student = build_student(key, self.cfg, self.qcfg, teacher)
         # order matters: calibrate S_a first, THEN invert Eq. 2 for S_wR
         student = calibrate_student(student, self.cfg, self.qcfg,
-                                    self.teacher, calib_batches)
+                                    teacher, calib_batches)
         return init_scales(student, self.cfg, self.qcfg,
                            cle_init=self.qft.cle_init, plan=self.plan)
 

@@ -374,7 +374,7 @@ def test_check_cli_unknown_config_is_usage_error(capsys):
 
 
 # ---------------------------------------------------------------------------
-# Satellite: launch.hlo_analysis.cost_summary list/dict compat
+# launch.hlo_analysis.cost_summary
 # ---------------------------------------------------------------------------
 
 class _Compiled:
@@ -388,16 +388,6 @@ class _Compiled:
 def test_cost_summary_dict_shaped():
     got = cost_summary(_Compiled({"flops": 12.0, "bytes accessed": 34.0}))
     assert got == {"flops": 12.0, "bytes": 34.0}
-
-
-def test_cost_summary_list_shaped():
-    # jax <= 0.4.x: one dict per device kind
-    got = cost_summary(_Compiled([{"flops": 5.0, "bytes accessed": 6.0}]))
-    assert got == {"flops": 5.0, "bytes": 6.0}
-
-
-def test_cost_summary_empty_list():
-    assert cost_summary(_Compiled([])) == {"flops": 0.0, "bytes": 0.0}
 
 
 def test_cost_summary_real_lowering():

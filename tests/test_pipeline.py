@@ -1,6 +1,8 @@
 """End-to-end pipeline tests (fast tier): calibrate → init → finetune(2) →
 export → evaluate on the paper CNN and a tiny transformer, asserting
 export/dequantize_export parity and stage checkpoint resume."""
+import pathlib
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -152,3 +154,39 @@ def test_canonical_arch_spellings():
     assert canonical_arch("qwen3_8b") == "qwen3-8b"
     assert canonical_arch("qwen3-8b") == "qwen3-8b"
     assert canonical_arch("paper_cnn") == "paper-cnn"
+
+
+def test_reduced_config_is_one_chip_share_at_published_widths():
+    from repro.configs import get_config, registry
+    full = get_config("qwen3-8b")
+    cut = PipelineConfig(arch="qwen3_8b", smoke=False,
+                         reduced=True).model_config()
+    for f in ("d_model", "n_heads", "n_kv_heads", "head_dim", "d_ff"):
+        assert getattr(cut, f) == getattr(full, f), f
+    mod = registry.get_module("qwen3-8b")
+    assert (cut.n_layers, cut.vocab) == (mod.reduced["n_layers"],
+                                         mod.reduced["vocab"])
+    assert cut.vocab_padded == cut.vocab           # re-derived, not 151936
+    with pytest.raises(ValueError, match="REDUCED"):
+        get_config("paper-cnn", reduced=True)
+    with pytest.raises(ValueError, match="smoke and reduced"):
+        PipelineConfig(arch="qwen3_8b", smoke=True, reduced=True)
+
+
+def test_compile_cache_env_dir_wins_else_fixed_checkout_dir(monkeypatch,
+                                                            tmp_path):
+    import jax
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before  # sets no other
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        got = compile_cache.enable_compile_cache()
+        assert got == str(compile_cache.DEFAULT_DIR)
+        assert jax.config.jax_compilation_cache_dir == got
+        assert compile_cache.DEFAULT_DIR.parent == \
+            pathlib.Path(__file__).resolve().parents[1]
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -200,16 +200,16 @@ def test_plan_view_scoping():
 
 
 def test_mesh_context_provides_ambient_mesh():
-    """Regression (ROADMAP dryrun item): mesh_context must install an
-    ambient mesh so constrain_act's bare-PartitionSpec sharding constraint
-    traces on this jax version — the nullcontext fallback broke every
-    dryrun prefill/decode cell."""
+    """Regression (ROADMAP dryrun item): entering a launch.mesh mesh with
+    jax.set_mesh must install an ambient mesh so constrain_act's
+    bare-PartitionSpec sharding constraint traces — a missing ambient mesh
+    broke every dryrun prefill/decode cell."""
     from jax.sharding import PartitionSpec as P
-    from repro.launch.mesh import _make_mesh, mesh_context
-    mesh = _make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_host_mesh
+    mesh = make_host_mesh()
 
     def f(x):
         return jax.lax.with_sharding_constraint(x, P("data", None)) * 2
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         jax.jit(f).lower(jnp.ones((2, 2)))    # raises without an ambient mesh

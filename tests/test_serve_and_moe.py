@@ -149,9 +149,9 @@ def test_ep_shard_map_matches_sorted_dispatch():
         from repro.models.config import ModelConfig, MoEConfig
         from repro.models.moe import init_moe, moe_sorted
         from repro.sharding.ep import make_ep_moe
-        from repro.launch.mesh import _make_mesh, mesh_context
+        from repro.launch.mesh import make_elastic_mesh
         from repro.core import deployment_oriented
-        mesh = _make_mesh((2, 4), ("data", "model"))
+        mesh = make_elastic_mesh(8, model_parallel=4)
         qcfg = deployment_oriented()
         cfg = ModelConfig(name="m", family="moe", n_layers=1, d_model=32,
                           n_heads=4, n_kv_heads=4, d_ff=0, vocab=64,
@@ -162,7 +162,7 @@ def test_ep_shard_map_matches_sorted_dispatch():
         p = init_moe(key, cfg, qcfg)
         x = jax.random.normal(key, (2, 16, 32), jnp.float32)
         y_ref = moe_sorted(x.reshape(-1, 32), p, cfg, qcfg).reshape(2, 16, 32)
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             moe_fn = make_ep_moe(mesh, cfg, qcfg, dp_axes=("data",))
             y = jax.jit(lambda x, p: moe_fn(x, p))(x, p)
             g = jax.jit(jax.grad(lambda p, x: jnp.sum(moe_fn(x, p)**2)))(p, x)
@@ -175,5 +175,5 @@ def test_ep_shard_map_matches_sorted_dispatch():
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, timeout=300,
                          env={**__import__("os").environ,
-                              "PYTHONPATH": "src"})
+                              "PYTHONPATH": "src", "JAX_PLATFORMS": "cpu"})
     assert "EP_TEST_OK" in out.stdout, out.stderr[-2000:]

@@ -170,3 +170,44 @@ def test_calib_data_deterministic_and_seekable():
     c.skip_to(5)
     np.testing.assert_array_equal(next(iter(a))["tokens"],
                                   next(iter(c))["tokens"])
+
+
+def test_sharded_qft_matches_single_device_first_step():
+    """launch/train.sharded_qft on a 4-device mesh: the state lands spread
+    over the devices, and the first QFT step from one init gives the same
+    loss and grad norm sharded as on device 0 alone — chip_smoke.py's
+    ``--four-chips`` check, on 4 virtual CPU devices at SMOKE size.
+
+    A subprocess: the device count is fixed when jax starts."""
+    import os
+    import pathlib
+    import subprocess
+    import sys
+    import textwrap
+    root = pathlib.Path(__file__).resolve().parents[1]
+    code = textwrap.dedent("""
+        import os, sys
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        sys.path.insert(0, ".")
+        import jax
+        import chip_smoke as cs
+        from repro.configs import get_config
+        from repro.launch.mesh import make_elastic_mesh
+        from repro.models import set_runtime
+        set_runtime(act_spec=("data",))
+        mesh = make_elastic_mesh(4, model_parallel=4)
+        cfg = get_config("qwen3-8b", smoke=True)
+        metrics, live = cs.sharded_steps(cfg, mesh, steps=1)
+        assert sorted(live) == [0, 1, 2, 3], live
+        assert max(live.values()) < sum(live.values()) / 2, live
+        sharded, single = cs.first_step_pair(cfg, mesh, jax.devices()[0])
+        for k in ("loss", "grad_norm"):
+            rel = abs(sharded[k] - single[k]) / abs(single[k])
+            assert rel <= cs.SHARDED_STEP_RTOL, (k, sharded, single)
+        print("SHARDED_QFT_OK")
+    """)
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=300, cwd=root,
+                         env={**os.environ, "PYTHONPATH": str(root / "src"),
+                              "JAX_PLATFORMS": "cpu"})
+    assert "SHARDED_QFT_OK" in out.stdout, out.stderr[-2000:]
