@@ -59,6 +59,13 @@ from .deploy import (DeployPlan, deploy_view, export_for_layers,
                      plan_from_artifact)
 from .kv_cache import (BUCKETED_PREFILL_FAMILIES, KVSpec, PageAllocator,
                        bucket_for, resolve_kv_spec)
+from .spans import span
+
+#: the totals Engine.stats() reports since reset(); each is what step()'s
+#: spans of the same phase carry as arguments, summed
+STEP_COUNTERS = ("steps", "prefill_chunks", "prefill_tokens",
+                 "prefill_bucket_tokens", "installs", "decode_steps",
+                 "decode_live_slot_rows", "tokens_emitted", "retires")
 
 
 @dataclasses.dataclass
@@ -508,6 +515,7 @@ class Engine:
         self._consumers: dict[int, TokenStream | Callable[[int, bool], None]]\
             = {}                                  # rid -> stream / callback
         self._work: dict[int, int] = {}           # rid -> step-count estimate
+        self._counts = dict.fromkeys(STEP_COUNTERS, 0)
         self._cache_bytes = _tree_bytes(self.cache) + _tree_bytes(self.state)
         self._peak_live_bytes = (self._params_bytes + self._artifact_bytes
                                  + self._cache_bytes)
@@ -518,16 +526,17 @@ class Engine:
                 + len(self._prefilling) * self._prefill_slot_bytes)
 
     def stats(self) -> dict[str, int]:
-        """Cheap accounting snapshot for benchmarks and ops dashboards.
+        """Cheap accounting snapshot: the serving benchmark logs it before
+        and after its window (``bench/kinds/serve.py``), and operators read
+        it as a running engine's counters.
 
         Buffer sizes are computed from array shapes/dtypes (params + the
         exported artifact the engine retains + the slot cache & decode
         state + one batch-1 cache per prefilling slot) rather than sampled
-        from the OS — deterministic across machines, which is what lets
-        ``peak_live_bytes`` live in the tracked benchmark history.
-        ``peak_live_bytes`` is high-watermarked at every step() (prefill
-        concurrency is the only dynamic term; everything else is fixed at
-        reset()).
+        from the OS, so they cost no device sync and read the same on every
+        machine.  ``peak_live_bytes`` is high-watermarked at every step()
+        (prefill concurrency is the only dynamic term; everything else is
+        fixed at reset()).
 
         ``decode_attn_pallas_layers`` / ``decode_attn_ref_layers`` report the
         per-layer kernel route of the slot decode step: how many attention
@@ -535,6 +544,14 @@ class Engine:
         masked-XLA reference, per models/attention.decode_route — the same
         predicate the forward uses, so the counters can't drift from the
         actual trace.
+
+        The totals since reset() (``STEP_COUNTERS``) count what step() did:
+        ``steps``; ``prefill_chunks``, with their real ``prefill_tokens``
+        and the ``prefill_bucket_tokens`` computed for them (bucket
+        padding included); ``installs``; ``decode_steps``, with
+        ``decode_live_slot_rows`` the live slots summed over them (against
+        ``max_slots`` rows each); ``tokens_emitted``; ``retires``.  Each is
+        the sum of the matching argument of step()'s spans.
         """
         n_attn = _attn_layer_count(self.cfg)
         depth = (self._kv.view_len if self._kv is not None
@@ -562,6 +579,7 @@ class Engine:
             "kv_page_size": 0 if self._kv is None else self._kv.page_size,
             "kv_pages_total": 0 if self._kv is None else self._kv.n_pages,
             "kv_pages_free": 0 if self._pager is None else self._pager.n_free,
+            **self._counts,
         }
 
     # ------------------------------------------------------------ serve API
@@ -652,9 +670,100 @@ class Engine:
         2. chunked prefill: each prefilling slot advances one prompt chunk
            in its own batch-1 cache; finished prefills are scattered into
            the slot cache and the slot activates;
-        3. decode: ONE jitted call over all slots + ONE host transfer.
+        3. decode: ONE jitted call over all slots + ONE host transfer;
+        4. delivery: each live slot's token goes to its consumer, and each
+           finished slot is retired.
+
+        Each phase is a profiler span (serve/spans.py; nothing is recorded
+        while no profiler session runs), nested as the code nests:
+        ``engine.step`` holds ``engine.admit`` (``admitted``), one
+        ``engine.prefill`` per prompt chunk (``rid``, ``slot``, ``tokens``,
+        ``bucket``: the real and the computed length), one
+        ``engine.install`` per finished prefill (``rid``, ``slot``,
+        ``plen``), ``engine.decode`` (``live``, ``slots``), ``engine.sync``
+        (the transfer) and ``engine.deliver`` (``emitted``, ``finished``),
+        which holds one ``engine.retire`` (``slot``) per finished slot.
+        The same numbers are added to the totals in ``stats()``.
         """
-        scfg = self.scfg
+        scfg, count = self.scfg, self._counts
+        with span("engine.step"):
+            count["steps"] += 1
+            with span("engine.admit") as sp:
+                admitted = self._admit()
+                sp.set_metadata(admitted=admitted)
+            # prefill concurrency peaks right after admission, before installs
+            self._peak_live_bytes = max(self._peak_live_bytes,
+                                        self._live_bytes())
+
+            for slot in sorted(self._prefilling):
+                st = self._prefilling[slot]
+                req, off = st["req"], st["off"]
+                k = min(scfg.prefill_chunk, len(req.prompt) - off)
+                # pad-and-mask to the fixed bucket menu: compiled prefill
+                # traces are bounded by the menu, not by prompt lengths
+                b = bucket_for(k, scfg.prefill_chunk) if self._bucketed else k
+                count["prefill_chunks"] += 1
+                count["prefill_tokens"] += k
+                count["prefill_bucket_tokens"] += b
+                with span("engine.prefill", rid=req.rid, slot=slot, tokens=k,
+                          bucket=b):
+                    chunk = list(req.prompt[off: off + k])
+                    if self._bucketed:
+                        toks = jnp.asarray([chunk + [0] * (b - k)], jnp.int32)
+                        logits, st["cache"] = self._prefill_b(
+                            self.params, st["cache"], {"tokens": toks},
+                            jnp.asarray(k, jnp.int32))
+                    else:
+                        toks = jnp.asarray([chunk], jnp.int32)
+                        logits, st["cache"] = self._prefill(
+                            self.params, st["cache"], {"tokens": toks})
+                st["off"] = off + k
+                if st["off"] == len(req.prompt):
+                    count["installs"] += 1
+                    with span("engine.install", rid=req.rid, slot=slot,
+                              plen=len(req.prompt)):
+                        self._install(slot, st, logits[0])
+                    self._alive.add(slot)
+                    del self._prefilling[slot]
+            self._peak_slots = max(self._peak_slots, len(self._alive))
+
+            finished: dict[int, list[int]] = {}
+            if not self._alive:
+                return finished
+            live = len(self._alive)
+            count["decode_steps"] += 1
+            count["decode_live_slot_rows"] += live
+            with span("engine.decode", live=live, slots=scfg.max_slots):
+                self.cache, self.state, emitted, emit = self._decode(
+                    self.params, self.cache, self.state)
+            with span("engine.sync"):
+                toks_h, emit_h, done_h = jax.device_get(  # qft: noqa[QFT003]
+                    (emitted, emit, self.state["done"]))  # the step's ONE sync
+            with span("engine.deliver") as sp:
+                n_emitted = n_finished = 0
+                for slot in sorted(self._alive):
+                    rid = self.sched.running[slot]
+                    if emit_h[slot]:
+                        n_emitted += 1
+                        self._deliver(rid, int(toks_h[slot]),
+                                      bool(done_h[slot]))
+                    if done_h[slot]:
+                        n_finished += 1
+                        with span("engine.retire", slot=slot):
+                            self._retire(slot)
+                        del self._work[rid]
+                        toks = self._finish_rid(rid)
+                        if toks is not None:
+                            finished[rid] = toks
+                count["tokens_emitted"] += n_emitted
+                count["retires"] += n_finished
+                sp.set_metadata(emitted=n_emitted, finished=n_finished)
+            return finished
+
+    def _admit(self) -> int:
+        """Admission: move queued requests into free slots, each with a
+        fresh batch-1 prefill cache (and, paged, its reserved pages).
+        Returns how many were admitted."""
         can = None
         reserved: dict[int, list[int]] = {}      # rid -> pages, this round
         if self._pager is not None:
@@ -669,81 +778,47 @@ class Engine:
                     return False
                 reserved[r.rid] = self._pager.alloc(n)
                 return True
-        for slot, req in self.sched.admit(can):
+        admitted = self.sched.admit(can)
+        for slot, req in admitted:
             st = {"req": req, "off": 0,
-                  "cache": init_cache(self.cfg, 1, scfg.max_len)}
+                  "cache": init_cache(self.cfg, 1, self.scfg.max_len)}
             if self._pager is not None:
                 st["pages"] = reserved.pop(req.rid)
             self._prefilling[slot] = st
         assert not reserved       # every reservation was claimed by a slot
-        # prefill concurrency peaks right after admission, before installs
-        self._peak_live_bytes = max(self._peak_live_bytes, self._live_bytes())
+        return len(admitted)
 
-        for slot in sorted(self._prefilling):
-            st = self._prefilling[slot]
-            req, off = st["req"], st["off"]
-            chunk = list(req.prompt[off: off + scfg.prefill_chunk])
-            if self._bucketed:
-                # pad-and-mask to the fixed bucket menu: compiled prefill
-                # traces are bounded by the menu, not by prompt lengths
-                b = bucket_for(len(chunk), scfg.prefill_chunk)
-                toks = jnp.asarray([chunk + [0] * (b - len(chunk))],
-                                   jnp.int32)
-                logits, st["cache"] = self._prefill_b(
-                    self.params, st["cache"], {"tokens": toks},
-                    jnp.asarray(len(chunk), jnp.int32))
-            else:
-                toks = jnp.asarray([chunk], jnp.int32)
-                logits, st["cache"] = self._prefill(self.params, st["cache"],
-                                                    {"tokens": toks})
-            st["off"] = off + len(chunk)
-            if st["off"] == len(req.prompt):
-                if self._kv is not None:
-                    pages = st["pages"]
-                    padded = pages + [self._kv.trash_page] * (
-                        self._kv.max_pages_per_slot - len(pages))
-                    self.cache, self.state = _PAGED_INSTALL(
-                        self.cache, self.state, st["cache"], slot,
-                        jnp.asarray(padded, jnp.int32), logits[0],
-                        len(req.prompt), req.max_new_tokens, req.eos_id,
-                        req.temperature, req.top_k, req.top_p, req.seed,
-                        page_size=self._kv.page_size,
-                        mmse_iters=self._mmse_iters)
-                    self._slot_pages[slot] = pages
-                else:
-                    self.cache, self.state = _INSTALL(
-                        self.cache, self.state, st["cache"], slot, logits[0],
-                        len(req.prompt), req.max_new_tokens, req.eos_id,
-                        req.temperature, req.top_k, req.top_p, req.seed)
-                self._alive.add(slot)
-                del self._prefilling[slot]
-        self._peak_slots = max(self._peak_slots, len(self._alive))
+    def _install(self, slot: int, st: dict, last_logits) -> None:
+        """Scatter a finished prefill (``st``) into ``slot`` of the slot
+        cache and activate the slot: into its reserved pages, quantized,
+        when paged; into its row of the monolithic cache otherwise."""
+        req = st["req"]
+        if self._kv is not None:
+            pages = st["pages"]
+            padded = pages + [self._kv.trash_page] * (
+                self._kv.max_pages_per_slot - len(pages))
+            self.cache, self.state = _PAGED_INSTALL(
+                self.cache, self.state, st["cache"], slot,
+                jnp.asarray(padded, jnp.int32), last_logits,
+                len(req.prompt), req.max_new_tokens, req.eos_id,
+                req.temperature, req.top_k, req.top_p, req.seed,
+                page_size=self._kv.page_size, mmse_iters=self._mmse_iters)
+            self._slot_pages[slot] = pages
+        else:
+            self.cache, self.state = _INSTALL(
+                self.cache, self.state, st["cache"], slot, last_logits,
+                len(req.prompt), req.max_new_tokens, req.eos_id,
+                req.temperature, req.top_k, req.top_p, req.seed)
 
-        finished: dict[int, list[int]] = {}
-        if self._alive:
-            self.cache, self.state, emitted, emit = self._decode(
-                self.params, self.cache, self.state)
-            toks_h, emit_h, done_h = jax.device_get(  # qft: noqa[QFT003]
-                (emitted, emit, self.state["done"]))  # the step's ONE sync
-            for slot in sorted(self._alive):
-                rid = self.sched.running[slot]
-                if emit_h[slot]:
-                    self._deliver(rid, int(toks_h[slot]), bool(done_h[slot]))
-                if done_h[slot]:
-                    self.sched.evict(slot)
-                    self._alive.discard(slot)
-                    if self._pager is not None:
-                        # before the next decode step: redirect the slot's
-                        # page-table row to the trash page, then hand its
-                        # pages back to the pool for reuse
-                        self.cache = _RETIRE(self.cache, slot,
-                                             self._kv.trash_page)
-                        self._pager.release(self._slot_pages.pop(slot))
-                    del self._work[rid]
-                    toks = self._finish_rid(rid)
-                    if toks is not None:
-                        finished[rid] = toks
-        return finished
+    def _retire(self, slot: int) -> None:
+        """Hand a finished slot back to the scheduler; paged, redirect its
+        page-table row to the trash page before the next decode step, then
+        return its pages to the pool for reuse."""
+        self.sched.evict(slot)
+        self._alive.discard(slot)
+        if self._pager is not None:
+            self.cache = _RETIRE(self.cache, slot, self._kv.trash_page)
+            self._pager.release(self._slot_pages.pop(slot))
 
     def _pages_needed(self, req: Request) -> int:
         return self._kv.pages_for(len(req.prompt) + req.max_new_tokens)
