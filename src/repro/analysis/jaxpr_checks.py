@@ -38,9 +38,10 @@ plan-coverage    Every quantized site in the init tree resolves through the
                  The serve-time KV cache is a covered tensor class: a
                  standard-KV family whose plan lacks the ``kv_cache`` entry
                  fails (an f32-KV fallback would otherwise be silent).
-kernel-route     ``decode_route`` × ``_attn_layer_count`` predict whether
-                 the decode jaxpr contains a ``pallas_call``; the traced
-                 graph must agree in both routed and unrouted modes.
+kernel-route     ``decode_route`` (for the engine's paged or monolithic
+                 cache) × ``_attn_layer_count`` predict whether the decode
+                 jaxpr contains a ``pallas_call``; the traced graph must
+                 agree in both routed and unrouted modes.
 kv-cache         The traced decode cache agrees with the plan's KV entry:
                  int8 page pools + per-slot scale leaves + int32 page table
                  when the plan says int8 KV.  The scales are plain cache
@@ -234,6 +235,13 @@ def check_decode_transfers(arch: str, surfaces: dict,
                        message="decode step: one host-transfer surface")]
 
 
+def _route(cfg, scfg: ServeConfig, kv) -> bool:
+    """``decode_route`` for the engine's cache: paged (``kv``, a KVSpec)
+    or monolithic (None)."""
+    return decode_route(cfg, scfg.max_len, True,
+                        page_size=None if kv is None else kv.page_size)
+
+
 def check_kernel_route(arch: str, cfg, scfg: ServeConfig, deployed,
                        plan) -> list[Diagnostic]:
     diags = []
@@ -242,7 +250,7 @@ def check_kernel_route(arch: str, cfg, scfg: ServeConfig, deployed,
         s = serve_trace_surfaces(cfg, plan=p, scfg=scfg)
         closed = _trace(s["decode_fn"], deployed, s["cache"], s["state"])
         actual = has_pallas_call(closed)
-        expected = routed and decode_route(cfg, scfg.max_len, True) \
+        expected = routed and _route(cfg, scfg, s["kv"]) \
             and _attn_layer_count(cfg) > 0
         if actual != expected:
             diags.append(Diagnostic(
@@ -255,7 +263,7 @@ def check_kernel_route(arch: str, cfg, scfg: ServeConfig, deployed,
     if not diags:
         diags.append(Diagnostic(
             check="trace.kernel-route", config=arch, severity="info",
-            value=decode_route(cfg, scfg.max_len, True),
+            value=_route(cfg, scfg, s["kv"]),
             message="decode_route prediction matches traced graph "
                     "(routed and unrouted)"))
     return diags
@@ -381,9 +389,10 @@ def check_kv_cache(arch: str, cfg, surfaces: dict, plan) -> list[Diagnostic]:
                   (a materialized dequantized pool) and no ``mul`` at cache
                   extent (scales fold into q pre-dot / context post-dot,
                   never into the gathered KV) — witnessed non-vacuously by
-                  at least one int8 page gather.
-    kv-page-table the decode graph actually indexes pages: ≥1 int8 gather
-                  (the page read) and ≥1 int8 scatter (the token write).
+                  at least one page read: an int8 page gather, or the
+                  paged kernel's ``pallas_call`` on the int8 pool.
+    kv-page-table the decode graph actually indexes pages: ≥1 page read
+                  and ≥1 int8 scatter (the token write).
     """
     if cfg.family not in KV_CACHE_FAMILIES:
         return [Diagnostic(
@@ -438,6 +447,10 @@ def check_kv_cache(arch: str, cfg, surfaces: dict, plan) -> list[Diagnostic]:
         if name == "gather" and out_dt == jnp.int8 \
                 and getattr(out_aval, "ndim", 0) >= 4:
             int8_gathers += 1
+        elif name == "pallas_call" and any(
+                getattr(v.aval, "dtype", None) == jnp.int8
+                and v.aval.ndim >= 4 for v in eqn.invars):
+            int8_gathers += 1         # the paged kernel reads pages itself
         elif name.startswith("scatter") and out_dt == jnp.int8:
             int8_scatters += 1
         for v in eqn.outvars:
@@ -463,7 +476,7 @@ def check_kv_cache(arch: str, cfg, surfaces: dict, plan) -> list[Diagnostic]:
     elif int8_gathers == 0:
         diags.append(Diagnostic(
             check="trace.kv-fused", config=arch, value=0,
-            message="no int8 page gather in the decode jaxpr — the fused "
+            message="no int8 page read in the decode jaxpr — the fused "
                     "quant/dequant check would be vacuous"))
     else:
         diags.append(Diagnostic(
@@ -478,14 +491,14 @@ def check_kv_cache(arch: str, cfg, surfaces: dict, plan) -> list[Diagnostic]:
             check="trace.kv-page-table", config=arch, severity="info",
             value={"gathers": int8_gathers, "scatters": int8_scatters},
             message="decode indexes through the page table: "
-                    f"{int8_gathers} int8 page gather(s), "
+                    f"{int8_gathers} int8 page read(s), "
                     f"{int8_scatters} int8 token scatter(s)"))
     else:
         diags.append(Diagnostic(
             check="trace.kv-page-table", config=arch,
             value={"gathers": int8_gathers, "scatters": int8_scatters,
                    "pt_int32": pt_ok},
-            message="paged decode must gather int8 pages, scatter the new "
+            message="paged decode must read int8 pages, scatter the new "
                     "token int8, and carry an int32 page table — traced "
                     f"graph has gathers={int8_gathers}, "
                     f"scatters={int8_scatters}, pt_int32={pt_ok}"))

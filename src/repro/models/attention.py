@@ -14,7 +14,9 @@ import jax.numpy as jnp
 from ..core import dof
 from ..core.plan import plan_view
 from ..core.qconfig import QuantConfig
-from ..kernels.decode_attention import decode_attention, decode_tiles_ok
+from ..kernels.decode_attention import (decode_attention, decode_tiles_ok,
+                                        paged_decode_attention,
+                                        paged_decode_tiles_ok)
 from ..serve.kv_cache import quantize_kv
 from .config import ModelConfig
 from .layers import apply_mrope, apply_rope, rmsnorm, init_rmsnorm
@@ -23,15 +25,23 @@ Params = dict[str, Any]
 
 
 def decode_route(cfg: ModelConfig, max_len: int, use_pallas: bool,
-                 bk: int = 128) -> bool:
-    """Whether the vector-pos decode path routes through the Pallas
-    flash-decode kernel for a serving cache of depth ``max_len``.
+                 bk: int = 128, page_size: int | None = None) -> bool:
+    """Whether the vector-pos decode path routes through a Pallas
+    flash-decode kernel for a serving cache of depth ``max_len``: with
+    ``page_size`` (the paged int8 cache) the paged kernel, by
+    ``paged_decode_tiles_ok``; without, the monolithic kernel, by
+    ``decode_tiles_ok``.
 
     The single source of truth for kernel routing: :func:`attention` applies
     it at trace time and ``serve.engine.Engine.stats()`` reports it as
     per-layer route counters — they cannot disagree.  MLA layers never route
     (the latent-space decode is a different kernel, future work)."""
-    return bool(use_pallas) and cfg.mla is None and decode_tiles_ok(max_len, bk)
+    if not use_pallas or cfg.mla is not None:
+        return False
+    if page_size is not None:
+        return paged_decode_tiles_ok(page_size, cfg.n_kv_heads_padded,
+                                     cfg.head_dim)
+    return decode_tiles_ok(max_len, bk)
 
 
 # --------------------------------------------------------------------------
@@ -139,38 +149,43 @@ def _paged_decode(q: jax.Array, k: jax.Array, v: jax.Array, cache: Params,
                   interpret: bool | None) -> tuple[jax.Array, Params]:
     """One decode step over the paged int8 KV cache (serve, Sq == 1).
 
-    Cache leaves (per layer): ``k``/``v`` int8 page pools
-    ``[n_pages+1, P, Hkv, hd]`` (last page is the write-sink "trash" page),
-    ``k_scale``/``v_scale`` ``[S,Hkv]`` install-time MMSE scales, plus the
-    shared ``pt`` ``[S, max_pages]`` page table and ``pos`` ``[S]``.  The new
-    token is quantized with the slot's frozen scales and scattered into
-    (page, row); retired slots' pt rows all point at the trash page, so the
-    unconditional every-slot write never aliases a reused page.
+    Cache leaves, all layers' and ``layer``, the index of this one: the
+    int8 page pools ``k``/``v`` ``[L, n_pages+1, P, Hkv, hd]`` (last page
+    is the write-sink "trash" page); the install-time MMSE scales
+    ``k_scale``/``v_scale`` ``[L, S, Hkv]``; the page table ``pt``
+    ``[S, max_pages]`` and ``pos`` ``[S]``.  The new token is quantized
+    with the slot's frozen scales and scattered into (page, row) of the
+    stack in place; retired slots' pt rows all point at the trash page, so
+    the unconditional every-slot write never aliases a reused page, and
+    such a slot reads just that one page.
     """
-    pos, pt = cache["pos"], cache["pt"]
+    pos, pt, layer = cache["pos"], cache["pt"], cache["layer"]
     pool_k, pool_v = cache["k"], cache["v"]
-    ks, vs = cache["k_scale"], cache["v_scale"]
+    ks, vs = cache["k_scale"][layer], cache["v_scale"][layer]
     S, n_pg = pt.shape
-    P, Hkv, hd = pool_k.shape[1], pool_k.shape[2], pool_k.shape[3]
+    trash = pool_k.shape[1] - 1
+    P, Hkv, hd = pool_k.shape[2], pool_k.shape[3], pool_k.shape[4]
     H = q.shape[2]
     pg = pt[jnp.arange(S), jnp.minimum(pos // P, n_pg - 1)]
     row = pos % P
-    pool_k = pool_k.at[pg, row].set(quantize_kv(k[:, 0], ks))
-    pool_v = pool_v.at[pg, row].set(quantize_kv(v[:, 0], vs))
-    # gather each slot's pages into a transient [S,T,Hkv,hd] int8 view; rows
-    # past the slot's length (incl. trash-page garbage) are masked at compute
-    k8 = pool_k[pt].reshape(S, n_pg * P, Hkv, hd)
-    v8 = pool_v[pt].reshape(S, n_pg * P, Hkv, hd)
-    lengths = pos + 1
-    if decode_route(cfg, n_pg * P, use_pallas):
+    pool_k = pool_k.at[layer, pg, row].set(quantize_kv(k[:, 0], ks))
+    pool_v = pool_v.at[layer, pg, row].set(quantize_kv(v[:, 0], vs))
+    # a retired slot's pos keeps counting: its length is the trash page's
+    lengths = jnp.where(pt[:, 0] == trash, 1, pos + 1)
+    if decode_route(cfg, n_pg * P, use_pallas, page_size=P):
+        # the kernel reads each live page of the pool in place
         qd = q[:, 0].reshape(S, Hkv, H // Hkv, hd)
-        od = decode_attention(qd, k8, v8, lengths, k_scale=ks, v_scale=vs,
-                              interpret=interpret)
+        od = paged_decode_attention(qd, pool_k, pool_v, lengths, pt,
+                                    cache["k_scale"], cache["v_scale"],
+                                    layer=layer, interpret=interpret)
         out = od.reshape(S, 1, H, hd)
     else:
+        # gather each slot's pages into a transient [S,T,Hkv,hd] int8 view;
+        # rows past the slot's length (incl. trash-page garbage) are masked
+        k8 = pool_k[layer][pt].reshape(S, n_pg * P, Hkv, hd)
+        v8 = pool_v[layer][pt].reshape(S, n_pg * P, Hkv, hd)
         out = _paged_sdpa(q, k8, v8, lengths, ks, vs)
-    new_cache = {"k": pool_k, "v": pool_v, "k_scale": ks, "v_scale": vs,
-                 "pt": pt, "pos": pos + 1}
+    new_cache = {**cache, "k": pool_k, "v": pool_v, "pos": pos + 1}
     return out, new_cache
 
 
@@ -186,10 +201,12 @@ def attention(x: jax.Array, p: Params, cfg: ModelConfig,
     bits come from the resolved plan so training and export share one grid.
 
     ``use_pallas``: route the vector-pos decode step (continuous-batching
-    serving: per-slot offsets, Sq == 1) through the slot-masked flash-decode
-    kernel (kernels/decode_attention.py), gated by :func:`decode_route`; the
-    masked-XLA `_sdpa` below stays the oracle and the fallback.  All other
-    modes (train, prefill, scalar-pos decode) are unaffected.
+    serving: per-slot offsets, Sq == 1) through a flash-decode kernel
+    (kernels/decode_attention.py: the paged kernel for the paged int8 cache,
+    the slot-masked one for the monolithic cache), gated by
+    :func:`decode_route`; the masked-XLA `_paged_sdpa` / `_sdpa` stay the
+    oracles and the fallbacks.  All other modes (train, prefill, scalar-pos
+    decode) are unaffected.
     """
     B, Sq, _ = x.shape
     hd = cfg.head_dim

@@ -345,28 +345,47 @@ def forward(params: Params, cfg: ModelConfig, qcfg: QuantConfig | None,
     if fam in ("dense", "moe", "mla_moe", "vlm"):
         # "pos" (and the paged-KV page table "pt") are shared across layers:
         # excluded from the per-layer scan tree, re-injected into every
-        # layer's cache view, threaded through unchanged
-        ck = None if cache is None else {k: cache[k] for k in cache
-                                         if k not in ("pos", "pt")}
-        pos = None if cache is None else cache["pos"]
+        # layer's cache view, threaded through unchanged.  The paged cache's
+        # [L, ...] stacks stay whole too, each layer handed them with its
+        # index (a per-layer slice of a stack is a copy of it every step):
+        # the int8 pools "k"/"v", which each layer writes in place, ride the
+        # layer loop as carry; the scales only ride along
         pt = None if cache is None else cache.get("pt")
+        shared = ("pos", "pt") if pt is None else (
+            "pos", "pt", "k", "v", "k_scale", "v_scale")
+        ck = None if cache is None else {k: cache[k] for k in cache
+                                         if k not in shared}
+        pos = None if cache is None else cache["pos"]
+        pools = None
+        if pt is not None:
+            pools = (cache["k"], cache["v"])
+            ck["layer"] = jnp.arange(cfg.n_layers, dtype=jnp.int32)
 
-        def body(h, lp, cs, i):
-            c = None if cs is None else {
-                **cs, "pos": pos, **({} if pt is None else {"pt": pt})}
+        def body(carry, lp, cs, i):
+            h, pools = carry
+            c = None if cs is None else {**cs, "pos": pos}
+            if pt is not None:
+                c.update(pt=pt, k=pools[0], v=pools[1],
+                         k_scale=cache["k_scale"], v_scale=cache["v_scale"])
             h, nc = _attn_block(h, lp, cfg, qcfg, positions, c, taps,
                                 f"L{i}" if i is not None else "L",
                                 plan=pv.child("layers"),
                                 use_pallas=use_pallas, interpret=interpret)
             if nc is not None:
-                nc = {k: v for k, v in nc.items() if k not in ("pos", "pt")}
-            return h, nc
+                if pt is not None:
+                    pools = (nc["k"], nc["v"])
+                nc = {k: v for k, v in nc.items()
+                      if k not in shared and k != "layer"}
+            return (h, pools), nc
 
-        x, nk = _scan_layers(x, params["layers"], cfg, qcfg, positions, ck, body)
+        (x, pools), nk = _scan_layers((x, pools), params["layers"], cfg,
+                                      qcfg, positions, ck, body)
         if cache is not None:
             new_cache = {**nk, "pos": cache["pos"] + S}
             if pt is not None:
-                new_cache["pt"] = pt
+                new_cache.update(pt=pt, k=pools[0], v=pools[1],
+                                 k_scale=cache["k_scale"],
+                                 v_scale=cache["v_scale"])
 
     elif fam == "ssm":
         def body(h, lp, cs, i):

@@ -65,7 +65,8 @@ from .spans import span
 #: spans of the same phase carry as arguments, summed
 STEP_COUNTERS = ("steps", "prefill_chunks", "prefill_tokens",
                  "prefill_bucket_tokens", "installs", "decode_steps",
-                 "decode_live_slot_rows", "tokens_emitted", "retires")
+                 "decode_live_slot_rows", "decode_kv_pages",
+                 "tokens_emitted", "retires")
 
 
 @dataclasses.dataclass
@@ -506,6 +507,7 @@ class Engine:
         self._pager = (None if self._kv is None
                        else PageAllocator(self._kv.n_pages))
         self._slot_pages: dict[int, list[int]] = {}  # slot -> reserved pages
+        self._slot_pos: dict[int, int] = {}       # live slot -> its pos
         self._peak_slots = 0
         self._prefilling: dict[int, dict] = {}    # slot -> prefill progress
         self._alive: set[int] = set()
@@ -540,24 +542,28 @@ class Engine:
 
         ``decode_attn_pallas_layers`` / ``decode_attn_ref_layers`` report the
         per-layer kernel route of the slot decode step: how many attention
-        invocations go through the flash-decode Pallas kernel vs the
-        masked-XLA reference, per models/attention.decode_route — the same
-        predicate the forward uses, so the counters can't drift from the
-        actual trace.
+        invocations go through a flash-decode Pallas kernel (the paged one
+        for a paged cache) vs the masked-XLA reference, per
+        models/attention.decode_route — the same predicate the forward uses,
+        so the counters can't drift from the actual trace.
 
         The totals since reset() (``STEP_COUNTERS``) count what step() did:
         ``steps``; ``prefill_chunks``, with their real ``prefill_tokens``
         and the ``prefill_bucket_tokens`` computed for them (bucket
         padding included); ``installs``; ``decode_steps``, with
         ``decode_live_slot_rows`` the live slots summed over them (against
-        ``max_slots`` rows each); ``tokens_emitted``; ``retires``.  Each is
+        ``max_slots`` rows each) and ``decode_kv_pages`` the KV pages those
+        slots' attention reads, ``ceil(length / page_size)`` a slot (0 for
+        a monolithic cache; against ``max_slots * kv_pages_per_slot`` for
+        the padded view); ``tokens_emitted``; ``retires``.  Each is
         the sum of the matching argument of step()'s spans.
         """
         n_attn = _attn_layer_count(self.cfg)
-        depth = (self._kv.view_len if self._kv is not None
-                 else self.scfg.max_len)
-        routed = (n_attn if decode_route(self.cfg, depth,
-                                         self.plan.use_pallas) else 0)
+        kv = self._kv
+        depth = kv.view_len if kv is not None else self.scfg.max_len
+        routed = (n_attn if decode_route(
+            self.cfg, depth, self.plan.use_pallas,
+            page_size=None if kv is None else kv.page_size) else 0)
         live = self._live_bytes()
         return {
             "decode_attn_pallas_layers": routed,
@@ -680,9 +686,10 @@ class Engine:
         ``engine.prefill`` per prompt chunk (``rid``, ``slot``, ``tokens``,
         ``bucket``: the real and the computed length), one
         ``engine.install`` per finished prefill (``rid``, ``slot``,
-        ``plen``), ``engine.decode`` (``live``, ``slots``), ``engine.sync``
-        (the transfer) and ``engine.deliver`` (``emitted``, ``finished``),
-        which holds one ``engine.retire`` (``slot``) per finished slot.
+        ``plen``), ``engine.decode`` (``live``, ``slots``, ``kv_pages``),
+        ``engine.sync`` (the transfer) and ``engine.deliver`` (``emitted``,
+        ``finished``), which holds one ``engine.retire`` (``slot``) per
+        finished slot.
         The same numbers are added to the totals in ``stats()``.
         """
         scfg, count = self.scfg, self._counts
@@ -731,9 +738,12 @@ class Engine:
             if not self._alive:
                 return finished
             live = len(self._alive)
+            pages = self._decode_pages()
             count["decode_steps"] += 1
             count["decode_live_slot_rows"] += live
-            with span("engine.decode", live=live, slots=scfg.max_slots):
+            count["decode_kv_pages"] += pages
+            with span("engine.decode", live=live, slots=scfg.max_slots,
+                      kv_pages=pages):
                 self.cache, self.state, emitted, emit = self._decode(
                     self.params, self.cache, self.state)
             with span("engine.sync"):
@@ -804,6 +814,7 @@ class Engine:
                 req.temperature, req.top_k, req.top_p, req.seed,
                 page_size=self._kv.page_size, mmse_iters=self._mmse_iters)
             self._slot_pages[slot] = pages
+            self._slot_pos[slot] = len(req.prompt)
         else:
             self.cache, self.state = _INSTALL(
                 self.cache, self.state, st["cache"], slot, last_logits,
@@ -819,6 +830,20 @@ class Engine:
         if self._pager is not None:
             self.cache = _RETIRE(self.cache, slot, self._kv.trash_page)
             self._pager.release(self._slot_pages.pop(slot))
+            del self._slot_pos[slot]
+
+    def _decode_pages(self) -> int:
+        """KV pages this decode step's attention reads over the live slots,
+        ``ceil((pos + 1) / page_size)`` each (the token written at ``pos``
+        included), advancing every live slot's ``pos``; 0 when the cache
+        is not paged."""
+        if self._kv is None:
+            return 0
+        P, pages = self._kv.page_size, 0
+        for slot, pos in self._slot_pos.items():
+            pages += pos // P + 1
+            self._slot_pos[slot] = pos + 1
+        return pages
 
     def _pages_needed(self, req: Request) -> int:
         return self._kv.pages_for(len(req.prompt) + req.max_new_tokens)

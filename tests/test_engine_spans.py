@@ -92,6 +92,7 @@ def test_counters_by_hand_and_reset(engine):
         "prefill_bucket_tokens": 12,            # 3 tokens pad to bucket 4
         "installs": 3, "decode_steps": 8,
         "decode_live_slot_rows": 2 * 4 + 1 * 4,
+        "decode_kv_pages": 2 * 4 + 1 * 4,       # lengths 4-7: one page each
         "tokens_emitted": 12, "retires": 3}
     engine.reset()
     assert set(counters(engine).values()) == {0}
@@ -179,6 +180,7 @@ def test_span_arguments_sum_to_the_counters(session):
                                                            "admitted") == 5
     assert count("decode") == growth["decode_steps"]
     assert total("decode", "live") == growth["decode_live_slot_rows"]
+    assert total("decode", "kv_pages") == growth["decode_kv_pages"]
     assert {s[3]["slots"] for s in sp if s[0] == "engine.decode"} == {2}
     assert total("deliver", "emitted") == growth["tokens_emitted"] == 15
     assert total("deliver", "finished") == count("retire") \

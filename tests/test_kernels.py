@@ -9,6 +9,9 @@ from repro.core.fakequant import pack_int4
 from repro.kernels import (decode_attention, decode_tiles_ok,
                            fake_quant_kernel, flash_attention, quant_matmul)
 from repro.kernels import ref
+from repro.kernels.decode_attention import (paged_block_pages,
+                                            paged_decode_attention,
+                                            paged_decode_tiles_ok)
 
 
 @pytest.mark.parametrize("M,K,N,bm,bn,bk", [
@@ -101,6 +104,74 @@ def test_decode_tiles_ok_gate():
     assert decode_tiles_ok(96)              # bk clamps to max_len: one block
     assert not decode_tiles_ok(0)
     assert not decode_tiles_ok(200, bk=128)  # 200 % 128 != 0: no clean tiling
+
+
+@pytest.mark.parametrize("P,Hkv,G,hd,n_pg", [
+    (8, 2, 1, 64, 5),          # G 1 (Qwen2-MoE's grouping), hd 64
+    (16, 8, 4, 128, 40),       # Qwen3-8B's heads: 3 blocks of 16 pages
+    (32, 8, 3, 128, 20),       # G 3 (Phi-4-mini): 3 blocks of 8 pages
+    (16, 4, 3, 64, 6),
+    (32, 2, 4, 128, 3),
+])
+def test_paged_decode_attention_parity(P, Hkv, G, hd, n_pg):
+    """The paged kernel (interpret mode) vs `_paged_sdpa` over the gathered
+    view: lengths 1, P-1, P, P+1 and max_len, shuffled non-contiguous page
+    tables over a pool with spare pages, a retired slot whose table row all
+    points at the trash page, one layer of two-layer stacks.  The math
+    holds at any shape; `paged_decode_tiles_ok` decides where it runs on a
+    chip."""
+    from repro.models.attention import _paged_sdpa
+    T = n_pg * P
+    lengths = jnp.asarray([1, P - 1, P, P + 1, T, 1], jnp.int32)
+    S, N = lengths.shape[0], lengths.shape[0] * n_pg + 7
+    ks = jax.random.split(jax.random.PRNGKey(P * 1000 + Hkv * 100 + G), 6)
+    pool_k, pool_v = (jax.random.randint(k, (2, N + 1, P, Hkv, hd), -127,
+                                         128).astype(jnp.int8)
+                      for k in ks[:2])
+    pt = np.random.RandomState(P + hd).permutation(N)[:S * n_pg]
+    pt = pt.reshape(S, n_pg)
+    pt[-1] = N                                    # retired: the trash page
+    pt = jnp.asarray(pt, jnp.int32)
+    q = jax.random.normal(ks[2], (S, Hkv, G, hd), jnp.float32)
+    k_scale = jnp.exp(0.3 * jax.random.normal(ks[3], (2, S, Hkv))) * 0.02
+    v_scale = jnp.exp(0.3 * jax.random.normal(ks[4], (2, S, Hkv))) * 0.02
+    out = paged_decode_attention(q, pool_k, pool_v, lengths, pt, k_scale,
+                                 v_scale, layer=1)
+    k8 = pool_k[1][pt].reshape(S, T, Hkv, hd)
+    v8 = pool_v[1][pt].reshape(S, T, Hkv, hd)
+    want = _paged_sdpa(q.reshape(S, 1, Hkv * G, hd), k8, v8, lengths,
+                       k_scale[1], v_scale[1])
+    np.testing.assert_allclose(np.asarray(out).reshape(want.shape),
+                               np.asarray(want), rtol=1e-5, atol=1e-5)
+
+
+def test_bf16_split_is_exact():
+    """The paged kernel's dots rest on f32 == the sum of its three bf16
+    pieces, exactly, from tiny to huge magnitudes."""
+    from repro.kernels.decode_attention import _bf16_split
+    a = jax.random.normal(jax.random.PRNGKey(3), (64, 128), jnp.float32)
+    a = a * jnp.exp2(jnp.arange(64, dtype=jnp.float32)[:, None] * 3 - 90)
+    parts = np.asarray(_bf16_split(a).astype(jnp.float32), np.float64)
+    np.testing.assert_array_equal(parts[:64] + parts[64:128] + parts[128:],
+                                  np.asarray(a, np.float64))
+
+
+def test_paged_decode_tiles_ok_gate():
+    """A page's [P*Hkv, hd] slab is a free view of the int8 pool only with
+    whole (8, 128) tiles of heads, and lands on an int8 (32, 128) VMEM tile
+    only with P*Hkv a multiple of 32; a block is 256 KiB of K."""
+    assert paged_decode_tiles_ok(16, 8, 128)      # Qwen3, Phi-4-mini
+    assert paged_decode_tiles_ok(16, 16, 128)     # Qwen2-MoE: 16 KV heads
+    assert paged_decode_tiles_ok(8, 8, 128) and paged_decode_tiles_ok(4, 8, 256)
+    assert not paged_decode_tiles_ok(16, 4, 128)  # Qwen2-VL: 4 KV heads
+    assert not paged_decode_tiles_ok(16, 8, 96)   # lanes not whole tiles
+    assert not paged_decode_tiles_ok(16, 8, 64)
+    assert not paged_decode_tiles_ok(2, 8, 128)   # 16 rows: half a tile
+    assert not paged_decode_tiles_ok(16, 2, 16)   # the smoke configs
+    assert not paged_decode_tiles_ok(0, 8, 128)
+    assert paged_block_pages(16, 8, 128) == 16    # 256 tokens a block
+    assert paged_block_pages(32, 8, 128) == 8
+    assert paged_block_pages(16, 64, 256) == 1
 
 
 @pytest.mark.parametrize("R,C,bits", [(64, 128, 4), (128, 128, 8), (32, 256, 4)])

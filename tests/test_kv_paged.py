@@ -38,6 +38,7 @@ from repro.core.mmse import ppq_scale
 from repro.kernels.decode_attention import decode_attention
 from repro.models import ModelConfig, init_model
 from repro.models.config import MoEConfig
+from repro.serve.deploy import make_deploy_plan
 from repro.serve.engine import Engine, Request, ServeConfig
 from repro.serve.kv_cache import (KVSpec, PageAllocator, bucket_for,
                                   prefill_buckets, quantize_kv,
@@ -373,3 +374,115 @@ def test_analyzer_prefill_budget_is_the_bucket_menu():
     from repro.analysis.jaxpr_checks import ANALYZER_SCFG
     chunk = ANALYZER_SCFG["prefill_chunk"]
     assert len(prefill_buckets(chunk)) < chunk   # strictly tighter than old
+
+
+# ---------------------------------------------------------------------------
+# The paged kernel: routing, structure of the routed step, pages read
+# ---------------------------------------------------------------------------
+
+#: heads the paged kernel can read in place (8 KV heads of 128; see
+#: kernels/decode_attention.paged_decode_tiles_ok), everything else tiny
+ROUTABLE = ModelConfig(name="routable", family="dense", n_layers=2,
+                       d_model=32, n_heads=16, n_kv_heads=8, d_ff=64,
+                       vocab=64, head_dim=128, scan_layers=False,
+                       remat=False)
+
+
+def _routed_decode_jaxpr(cfg, S=3, max_len=64):
+    from repro.serve.deploy import init_slot_cache, init_slot_state
+    from repro.train.steps import make_slot_decode_step
+    kv = resolve_kv_spec(cfg, ServeConfig(max_slots=S, max_len=max_len))
+    params = jax.eval_shape(lambda: init_model(jax.random.PRNGKey(0), cfg,
+                                               None))
+    cache = jax.eval_shape(lambda: init_slot_cache(cfg, S, max_len, kv=kv))
+    state = jax.eval_shape(lambda: init_slot_state(S))
+    step = make_slot_decode_step(cfg, None, use_pallas=True)
+    return jax.make_jaxpr(step)(params, cache, state), cache
+
+
+def test_routed_paged_decode_reads_the_pool_in_place():
+    """A routed paged decode step holds one pallas_call per attention
+    layer, reading the whole layer-stacked pool (as its page-slab view):
+    no gather of the pool, and nothing but the token's scatter and that
+    view makes an array of a layer's pool extent (no per-layer slice or
+    re-stack of it)."""
+    from repro.analysis.jaxpr_checks import iter_eqns
+    closed, cache = _routed_decode_jaxpr(ROUTABLE)
+    pool = cache["k"].shape                        # [L, n_pages+1, P, Hkv, hd]
+    eqns = list(iter_eqns(closed))
+    calls = [e for e in eqns if e.primitive.name == "pallas_call"]
+    assert len(calls) == ROUTABLE.n_layers
+    for e in calls:
+        assert sum(v.aval.dtype == jnp.int8 and v.aval.size == np.prod(pool)
+                   for v in e.invars) == 2        # the K and the V stack
+    assert not [e for e in eqns if e.primitive.name == "gather"
+                and e.outvars[0].aval.dtype == jnp.int8]
+    makers = {e.primitive.name for e in eqns for v in e.outvars
+              if getattr(v.aval, "size", 0) >= np.prod(pool[1:])}
+    assert makers == {"scatter", "reshape"}, makers
+
+
+def test_paged_route_agrees_across_engine_analyzer_and_trace():
+    """decode_route decides the paged kernel by the page's shape; the
+    engine's route counters and the analyzer's trace.kernel-route agree
+    with it, routed (8 KV heads of 128) and not (the tiny heads)."""
+    from repro.analysis.jaxpr_checks import ANALYZER_SCFG, check_kernel_route
+    from repro.models.attention import decode_route
+    from repro.serve.deploy import abstract_deploy_surfaces
+    for cfg, want in ((ROUTABLE, True), (CONFIGS["dense"], False)):
+        assert decode_route(cfg, 64, True, page_size=16) is want
+        assert not decode_route(cfg, 64, False, page_size=16)
+        params = init_model(jax.random.PRNGKey(0), cfg, permissive())
+        plan = make_deploy_plan(permissive(), arch=cfg.name,
+                                family=cfg.family, use_pallas=True,
+                                params=params, model_cfg=cfg)
+        stats = Engine(cfg, permissive(), params,
+                       ServeConfig(max_slots=2, max_len=64), plan=plan).stats()
+        assert stats["decode_attn_pallas_layers"] == (cfg.n_layers if want
+                                                      else 0)
+        plan, _, deployed = abstract_deploy_surfaces(cfg, permissive(),
+                                                     use_pallas=True)
+        (d,) = check_kernel_route(cfg.name, cfg,
+                                  ServeConfig(**ANALYZER_SCFG), deployed,
+                                  plan)
+        assert d.severity == "info" and d.value is want, d
+
+
+def test_decode_kv_pages_counts_live_pages():
+    """decode_kv_pages sums ceil(length / P) over the live slots of every
+    decode step, the token written this step included: a request of
+    prompt p and n new tokens decodes at lengths p+1 .. p+n."""
+    engine = Engine(CONFIGS["dense"], permissive(),
+                    init_model(jax.random.PRNGKey(0), CONFIGS["dense"],
+                               permissive()),
+                    ServeConfig(max_slots=2, max_len=64, prefill_chunk=8,
+                                kv_page_size=4))
+    reqs = [Request(prompt=list(range(1, 1 + p)), max_new_tokens=n)
+            for p, n in ((3, 6), (8, 5), (1, 9), (12, 4))]
+    engine.reset()
+    engine.generate(reqs)
+    want = sum(-(-(len(r.prompt) + i) // 4)
+               for r in reqs for i in range(1, r.max_new_tokens + 1))
+    s = engine.stats()
+    assert s["decode_kv_pages"] == want
+    assert s["decode_live_slot_rows"] == sum(r.max_new_tokens for r in reqs)
+
+
+def test_paged_kernel_serves_the_reference_route_tokens():
+    """An engine whose decode reads pages through the paged kernel emits
+    the tokens of the same engine on the gathered view and `_paged_sdpa`,
+    over slot refill and page reuse."""
+    params = init_model(jax.random.PRNGKey(0), ROUTABLE, permissive())
+    out = []
+    for use_pallas in (False, True):
+        plan = make_deploy_plan(permissive(), arch=ROUTABLE.name,
+                                family=ROUTABLE.family,
+                                use_pallas=use_pallas, params=params,
+                                model_cfg=ROUTABLE)
+        engine = Engine(ROUTABLE, permissive(), params,
+                        ServeConfig(max_slots=3, max_len=64,
+                                    prefill_chunk=8), plan=plan)
+        assert engine.stats()["decode_attn_pallas_layers"] == (
+            ROUTABLE.n_layers if use_pallas else 0)
+        out.append(engine.generate(REQS))
+    assert out[0] == out[1]

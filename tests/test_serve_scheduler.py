@@ -20,6 +20,7 @@ composition.  The token-streaming consumer API (Engine.stream /
 submit(on_token=...)) is covered at the end: emission order, ownership
 transfer, bounded memory.
 """
+import dataclasses
 import functools
 import math
 
@@ -145,8 +146,14 @@ def test_eos_stops_early_in_any_composition():
 @functools.lru_cache(maxsize=None)
 def routed_engine_for(family: str, max_slots: int = 3) -> Engine:
     """Engine whose DeployPlan routes the slot decode through the Pallas
-    flash-decode kernel (interpret mode on CPU)."""
+    flash-decode kernel (interpret mode on CPU): the paged kernel, so the
+    attention families get heads it can read in place (8 KV heads of 128,
+    models/attention.decode_route)."""
     cfg = CONFIGS[family]
+    if cfg.family != "ssm":
+        cfg = dataclasses.replace(cfg, n_heads=2 * 8 if family == "dense"
+                                  else 8, n_kv_heads=8, head_dim=128,
+                                  n_heads_padded=0, n_kv_heads_padded=0)
     params = init_model(jax.random.PRNGKey(0), cfg, permissive())
     plan = make_deploy_plan(permissive(), arch=cfg.name, family=cfg.family,
                             use_pallas=True, interpret=None, params=params,

@@ -14,6 +14,7 @@ from __future__ import annotations
 import importlib.util
 import os
 import pathlib
+import re
 import subprocess
 import sys
 
@@ -22,7 +23,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.decode_attention import decode_attention
+from repro.kernels.decode_attention import (decode_attention,
+                                            paged_decode_attention)
 from repro.kernels.quant_matmul import quant_matmul
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
@@ -72,6 +74,27 @@ def test_decode_attention_compiles_for_v5e(v5e, cache):
     c = _compile(v5e, lambda q, k, v, n, *sc: decode_attention(
         q, k, v, n, *sc, interpret=False), *shapes)
     assert "tpu_custom_call" in c.as_text()
+
+
+@pytest.mark.parametrize("S", [64, 128], ids=["chat", "batch"])
+def test_paged_decode_attention_compiles_for_v5e(v5e, S):
+    """The serving cells' shapes: S slots of max_len 4,096 in pages of 16,
+    a two-layer stacked int8 pool and scales.  Its VMEM blocks and the page
+    table in SMEM fit the chip, and the pool reaches the kernel as a
+    bitcast, not a copy."""
+    n_pg, P, L = 4096 // 16, 16, 2
+    pool = ((L, S * n_pg + 1, P, HKV, HD), jnp.int8)
+    c = _compile(v5e, lambda q, k, v, n, pt, ks, vs: paged_decode_attention(
+        q, k, v, n, pt, ks, vs, layer=1, interpret=False),
+        ((S, HKV, G, HD), jnp.bfloat16), pool, pool, ((S,), jnp.int32),
+        ((S, n_pg), jnp.int32), ((L, S, HKV), jnp.float32),
+        ((L, S, HKV), jnp.float32))
+    text = c.as_text()
+    assert "tpu_custom_call" in text
+    entry = text[text.index("\nENTRY"):]
+    pool_ops = re.findall(rf"= s8\[{L},{S * n_pg + 1},[\d,]*\]\{{[^}}]*\}} "
+                          r"([\w-]+)\(", entry)
+    assert set(pool_ops) == {"parameter", "bitcast"}, pool_ops
 
 
 def _chip_smoke():
