@@ -206,16 +206,22 @@ def _floats(metrics) -> dict:
     return {k: float(v) for k, v in metrics.items()}
 
 
+def _sharded(cfg, mesh, batches):
+    """launch/train's sharded trainer on ``mesh`` for batches shaped like
+    ``batches``, with its ``PRNGKey`` state from the first two."""
+    from repro.core import deployment_oriented
+    from repro.launch.train import ShardedQFT, batch_like, random_state
+    qft = ShardedQFT(cfg, deployment_oriented(), mesh, batch_like(batches[0]))
+    return qft, random_state(qft, batches[:2])
+
+
 def sharded_steps(cfg, mesh, steps: int):
     """``steps`` QFT steps of launch/train's sharded trainer on ``mesh``;
     returns the per-step metrics and the live state bytes per device."""
     import jax
-    from repro.core import deployment_oriented
-    from repro.launch.train import sharded_qft
     batches = _calib(cfg, 2 + steps)
     with jax.set_mesh(mesh):
-        student, opt_state, teacher, step = sharded_qft(
-            cfg, deployment_oriented(), mesh, batches[:2])
+        qft, (student, opt_state, teacher) = _sharded(cfg, mesh, batches)
         per_device: dict = {}
         for leaf in jax.tree.leaves((student, opt_state, teacher)):
             for shard in leaf.addressable_shards:
@@ -223,50 +229,46 @@ def sharded_steps(cfg, mesh, steps: int):
                 per_device[d] = per_device.get(d, 0) + shard.data.nbytes
         metrics = []
         for b in batches[2:]:
-            student, opt_state, m = step(student, opt_state, teacher, b)
+            student, opt_state, m = qft.step(
+                student, opt_state, teacher,
+                jax.device_put(b, qft.batch_sharding))
             metrics.append(_floats(m))
     return metrics, per_device
 
 
 def first_step_pair(cfg, mesh, device):
     """The first QFT step from one sharded init, run sharded on ``mesh`` and
-    again on ``device`` alone from a host copy of the same state.
+    again on ``device`` alone from a host copy of the same state (both at
+    the mesh's padded sizes).
 
     Both start from the same state on purpose: calibration takes activation
     maxima of a bf16 forward, whose rounding depends on how the matmuls are
     split, so two inits differ by a few tenths of a percent in some
     activation scales — a different start, not a sharding fault."""
     import jax
-    from repro.core import deployment_oriented
     from repro.launch.mesh import make_host_mesh
-    from repro.launch.train import sharded_qft
-    from repro.pipeline.adapters import resolve_quant_plan
-    from repro.train.qft_trainer import QFTTrainer
-    qcfg = deployment_oriented()
     batches = _calib(cfg, 3)
     with jax.set_mesh(mesh):
-        student, opt_state, teacher, step = sharded_qft(cfg, qcfg, mesh,
-                                                        batches[:2])
-        state = jax.device_get((student, opt_state, teacher))
-        sharded = _floats(step(student, opt_state, teacher, batches[2])[2])
+        qft, state = _sharded(cfg, mesh, batches)
+        student, opt_state, teacher = state
+        state = jax.device_get(state)
+        sharded = _floats(qft.step(student, opt_state, teacher,
+                                   jax.device_put(batches[2],
+                                                  qft.batch_sharding))[2])
     del student, opt_state, teacher
-    train_step = QFTTrainer(cfg, qcfg, None,
-                            plan=resolve_quant_plan(cfg, qcfg)).train_step
     with jax.set_mesh(make_host_mesh()):
         state = jax.device_put(state, device)
-        single = _floats(jax.jit(train_step, donate_argnums=(0, 1))(
-            *state, batches[2])[2])
+        single = _floats(jax.jit(qft.trainer.train_step,
+                                 donate_argnums=(0, 1))(*state, batches[2])[2])
     return sharded, single
 
 
 def four_chips(devices) -> None:
     from repro.configs.registry import get_config
     from repro.launch.mesh import make_elastic_mesh
-    from repro.models import set_runtime
     check(len(devices) == 4, f"four devices present ({len(devices)})")
     mesh = make_elastic_mesh(4, model_parallel=4)
     print(f"mesh: {dict(mesh.shape)} over devices {[d.id for d in devices]}")
-    set_runtime(act_spec=("data",))
 
     deep = dataclasses.replace(get_config(ARCH), n_layers=4)
     print(f"deep config: {deep.n_layers} layers, vocab {deep.vocab}, "

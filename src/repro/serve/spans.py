@@ -1,8 +1,9 @@
-"""Profiler spans of the serving engine.
+"""Profiler spans of the program: the serving engine's step phases and the
+sharded QFT set-up (``launch/train.ShardedQFT``).
 
 ``span(name, **args)`` is a ``jax.profiler.TraceAnnotation`` named
-``repro:<name>`` while a profiler session runs, so the engine's host phases
-land in the profiler's own trace, on the clock of the device's ops.  The
+``repro:<name>`` while a profiler session runs, so the host phases land in
+the profiler's own trace, on the clock of the device's ops.  The
 arguments are host integers the caller already holds; they travel as the
 event's stats.  With no session running it returns one shared context that
 does nothing: no annotation is built and no name is formatted.

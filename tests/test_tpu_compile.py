@@ -32,15 +32,20 @@ D, FF, HKV, G, HD = 4096, 12288, 8, 4, 128
 
 
 @pytest.fixture(scope="module")
-def v5e():
-    """One device of a described v5e:2x2 topology (no chip needed)."""
+def topo():
+    """A described v5e:2x2 topology (no chip needed)."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")   # no libtpu log files
     try:
         from jax.experimental import topologies
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:             # no libtpu, or it cannot describe one
         pytest.skip(f"cannot describe a v5e topology: {e}")
+
+
+@pytest.fixture(scope="module")
+def v5e(topo):
+    """One device of the described v5e:2x2."""
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -95,6 +100,38 @@ def test_paged_decode_attention_compiles_for_v5e(v5e, S):
     pool_ops = re.findall(rf"= s8\[{L},{S * n_pg + 1},[\d,]*\]\{{[^}}]*\}} "
                           r"([\w-]+)\(", entry)
     assert set(pool_ops) == {"parameter", "bitcast"}, pool_ops
+
+
+def test_sharded_qft_step_fits_four_v5e_chips(topo):
+    """The step of the four-chip cell ``qwen3-8b-tp4.qft`` (4 layers, the
+    151,936-row vocabulary padded to 152,064, 2 x 2,048 tokens, W4A8),
+    built by ``launch/train.ShardedQFT`` on a described v5e:2x2 mesh, data
+    1 x model 4: it compiles, fits a chip's 16 GB, and its all-reduces are
+    counted (one [2, 2,048, 4,096] bf16 activation each)."""
+    import dataclasses
+    import numpy as np
+    from jax.sharding import AxisType, Mesh
+    from repro.configs import get_config
+    from repro.core import deployment_oriented
+    from repro.launch.train import ShardedQFT
+    from repro.models import set_runtime
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    cfg = dataclasses.replace(get_config("qwen3-8b"), n_layers=4)
+    batch = {"tokens": jax.ShapeDtypeStruct((2, 2048), jnp.int32)}
+    try:
+        qft = ShardedQFT(cfg, deployment_oriented(), mesh, batch)
+    finally:
+        set_runtime(act_spec=None)     # the builder pins it process-wide
+    assert qft.cfg.vocab_padded == 152064
+    m = qft.compiled.memory_analysis()
+    per_device = (m.argument_size_in_bytes + m.output_size_in_bytes
+                  - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert per_device < 16e9, per_device
+    # per layer at least: the teacher's and the student's forward, its
+    # remat and its backward, two row-parallel sums each
+    activation = 2 * 2048 * 4096 * 2
+    assert qft.collective_bytes["all-reduce"] >= 8 * 4 * activation
 
 
 def _chip_smoke():

@@ -211,3 +211,34 @@ def test_sharded_qft_matches_single_device_first_step():
                          env={**os.environ, "PYTHONPATH": str(root / "src"),
                               "JAX_PLATFORMS": "cpu"})
     assert "SHARDED_QFT_OK" in out.stdout, out.stderr[-2000:]
+
+
+def test_sharded_qft_setup_phases_are_profiler_spans(tmp_path):
+    """``launch/train.ShardedQFT``'s set-up phases (placing the teacher,
+    preparing the student, initialising Adam) are ``repro:qft.*`` host
+    spans in a profiler session; on a one-device mesh at SMOKE size."""
+    import glob
+    from jax.profiler import ProfileData
+    from repro.configs import get_config
+    from repro.launch.mesh import make_host_mesh
+    from repro.launch.train import ShardedQFT, batch_like, random_state
+    from repro.models import set_runtime
+    cfg = get_config("qwen3-8b", smoke=True)
+    data = CalibDataset(CalibConfig(n_samples=16, seq_len=16, batch_size=2,
+                                    vocab=cfg.vocab))
+    calib = [{k: jnp.asarray(v) for k, v in next(iter(data)).items()}
+             for _ in range(2)]
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        qft = ShardedQFT(cfg, deployment_oriented(), make_host_mesh(),
+                         batch_like(calib[0]))
+        jax.block_until_ready(random_state(qft, calib))
+    finally:
+        jax.profiler.stop_trace()
+        set_runtime(act_spec=None)     # the builder pins it process-wide
+    (path,) = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    names = {e.name for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events}
+    assert {"repro:qft.teacher", "repro:qft.student",
+            "repro:qft.opt_init"} <= names
