@@ -1,7 +1,8 @@
 """Device-side stochastic decoding primitives for the serving engine.
 
-One function, :func:`sample_token`, maps ``(logits [V], key, temperature,
-top_k, top_p) -> token`` entirely on device, so the categorical draw can
+One function, :func:`sample_tokens`, maps ``(logits [S, V], keys,
+temperature, top_k, top_p, live) -> tokens [S]`` entirely on device
+(:func:`sample_token` is its one-row call), so the categorical draw can
 live *inside* the jitted slot-decode step (train/steps.make_slot_decode_step)
 without adding a host-transfer surface — the engine's one-transfer-per-step
 invariant survives sampling untouched (proved structurally by
@@ -10,8 +11,9 @@ invariant survives sampling untouched (proved structurally by
 Semantics (all knobs per request, all disabled by default):
 
 temperature  ``0`` (the default) is exact greedy argmax — the degenerate
-             path through the SAME traced step, selected with ``jnp.where``
-             so greedy and sampled requests share one compiled program.
+             path through the SAME traced step: greedy and sampled requests
+             share one compiled program, whose sampling work sits under a
+             ``lax.cond`` that a step with no live sampling slot skips.
              ``> 0`` scales logits by ``1/temperature`` before truncation.
 top_k        keep the ``k`` highest-logit tokens (``0`` disables).  Ties at
              the k-th logit are all kept, so the support is a function of
@@ -31,17 +33,18 @@ step one more).  A request's k-th token therefore depends only on its own
 conformance tier (tests/test_serve_scheduler.py) asserts bit-exactly.
 
 All ops are element-wise/sort/cumsum + ``jax.random`` (threefry) — pure
-device computation, jit/vmap-invariant: ``vmap(sample_token)`` over stacked
-slots draws exactly what per-slot calls would (tests/test_sampling.py).
+device computation, jit-invariant, and each row's draw is keyed by its own
+key alone: a row of ``sample_tokens`` draws exactly what ``sample_token``
+draws for that row by itself (tests/test_sampling.py).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-#: temperature floor for the scaled-logits path; the greedy branch is
+#: temperature floor for the scaled-logits path; greedy rows are
 #: selected by ``temperature > 0`` so this never changes a returned token,
-#: it only keeps the dead sampled branch finite at temperature == 0
+#: it only keeps their discarded draws finite at temperature == 0
 _TEMP_FLOOR = 1e-6
 
 _NEG_INF = float("-inf")
@@ -86,30 +89,62 @@ def top_p_mask(logits: jax.Array, p: jax.Array | float) -> jax.Array:
     return jnp.where((p >= 1.0) | (probs >= p_min), logits, _NEG_INF)
 
 
+@jax.jit
+def sample_tokens(logits: jax.Array, keys: jax.Array,
+                  temperature: jax.Array | float,
+                  top_k: jax.Array | int = 0,
+                  top_p: jax.Array | float = 1.0,
+                  live: jax.Array | None = None) -> jax.Array:
+    """Next-token draws for ``S`` slots: ``logits [S, V]``, ``keys [S, 2]``,
+    per-slot (or scalar) ``temperature``, ``top_k``, ``top_p`` -> int32 [S].
+
+    ``temperature == 0`` returns the exact argmax (bit-identical to the
+    pre-sampling greedy engine); ``> 0`` draws from the temperature-scaled,
+    top-k- then top-p-truncated categorical with the row's own key.
+
+    ``live [S]`` (all rows when None) marks the rows whose token is used.
+    The scaling, both masks and the categorical sit under one ``lax.cond``
+    that runs only when a live row samples, so a greedy step sorts nothing;
+    inside it, each mask runs only when a live sampling row has it enabled
+    (a disabled mask returns its input unchanged, so skipping it is exact).
+    A row outside ``live`` may get its argmax in place of its draw.  The
+    cond is why this is batched and not ``vmap(sample_token)``: under vmap
+    a cond becomes a select that runs both branches.  Jitted, so that an
+    eager call traces and compiles the conds once per shape.
+    """
+    s, v = logits.shape
+    temperature = jnp.broadcast_to(jnp.asarray(temperature, jnp.float32), (s,))
+    top_k = jnp.broadcast_to(jnp.asarray(top_k, jnp.int32), (s,))
+    top_p = jnp.broadcast_to(jnp.asarray(top_p, jnp.float32), (s,))
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    sampled = temperature > 0.0
+    asks = sampled if live is None else sampled & live
+
+    def keep(x, _):
+        return x
+
+    def draw():
+        scaled = (logits.astype(jnp.float32)
+                  / jnp.maximum(temperature, _TEMP_FLOOR)[:, None])
+        scaled = jax.lax.cond(jnp.any(asks & (top_k > 0) & (top_k < v)),
+                              jax.vmap(top_k_mask), keep, scaled, top_k)
+        masked = jax.lax.cond(jnp.any(asks & ~(top_p >= 1.0)),
+                              jax.vmap(top_p_mask), keep, scaled, top_p)
+        drawn = jax.vmap(lambda k, x: jax.random.categorical(k, x, axis=-1))(
+            keys, masked).astype(jnp.int32)
+        return jnp.where(sampled, drawn, greedy)
+
+    return jax.lax.cond(jnp.any(asks), draw, lambda: greedy)
+
+
 def sample_token(logits: jax.Array, key: jax.Array,
                  temperature: jax.Array | float,
                  top_k: jax.Array | int = 0,
                  top_p: jax.Array | float = 1.0) -> jax.Array:
-    """One next-token draw from one slot's logits ``[V]`` (int32 scalar).
-
-    ``temperature == 0`` returns the exact argmax (bit-identical to the
-    pre-sampling greedy engine); ``> 0`` draws from the temperature-scaled,
-    top-k- then top-p-truncated categorical.  Everything stays on device.
-    """
-    temperature = jnp.asarray(temperature, jnp.float32)
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    scaled = logits.astype(jnp.float32) / jnp.maximum(temperature, _TEMP_FLOOR)
-    masked = top_p_mask(top_k_mask(scaled, top_k), top_p)
-    drawn = jax.random.categorical(key, masked, axis=-1).astype(jnp.int32)
-    return jnp.where(temperature > 0.0, drawn, greedy)
-
-
-#: slot-vectorized draw: ``(logits [S, V], keys [S, 2], temperature [S],
-#: top_k [S], top_p [S]) -> tokens [S]`` — what the slot-decode step calls.
-#: vmap guarantees each slot's draw is exactly the per-slot sample_token
-#: (jax.random ops are vmap-invariant over per-element keys), so batch
-#: composition cannot leak into any slot's token stream.
-sample_tokens = jax.vmap(sample_token)
+    """One next-token draw from one slot's logits ``[V]`` (int32 scalar):
+    :func:`sample_tokens` on one live row."""
+    return sample_tokens(logits[None], jnp.asarray(key)[None], temperature,
+                         top_k, top_p)[0]
 
 
 def split_keys(keys: jax.Array) -> tuple[jax.Array, jax.Array]:

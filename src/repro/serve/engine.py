@@ -66,7 +66,7 @@ from .spans import span
 STEP_COUNTERS = ("steps", "prefill_chunks", "prefill_tokens",
                  "prefill_bucket_tokens", "installs", "decode_steps",
                  "decode_live_slot_rows", "decode_kv_pages",
-                 "tokens_emitted", "retires")
+                 "decode_sampled_steps", "tokens_emitted", "retires")
 
 
 @dataclasses.dataclass
@@ -511,6 +511,7 @@ class Engine:
         self._peak_slots = 0
         self._prefilling: dict[int, dict] = {}    # slot -> prefill progress
         self._alive: set[int] = set()
+        self._sampling: set[int] = set()          # live slots with temp > 0
         self._results: dict[int, list[int]] = {}  # in-flight token streams
         self._collected: dict[int, list[int]] = {}  # finished, drained by a
                                                     # foreign generate() call
@@ -555,8 +556,12 @@ class Engine:
         ``max_slots`` rows each) and ``decode_kv_pages`` the KV pages those
         slots' attention reads, ``ceil(length / page_size)`` a slot (0 for
         a monolithic cache; against ``max_slots * kv_pages_per_slot`` for
-        the padded view); ``tokens_emitted``; ``retires``.  Each is
-        the sum of the matching argument of step()'s spans.
+        the padded view); ``decode_sampled_steps``, the decode steps with a
+        live slot whose request samples (``temperature > 0``): the steps
+        that run the sampler's sort, softmax and categorical;
+        ``tokens_emitted``; ``retires``.  Each is the sum of the matching
+        argument of step()'s spans, but ``decode_sampled_steps`` counts the
+        ``engine.decode`` spans whose ``sampled`` is above 0.
         """
         n_attn = _attn_layer_count(self.cfg)
         kv = self._kv
@@ -686,7 +691,8 @@ class Engine:
         ``engine.prefill`` per prompt chunk (``rid``, ``slot``, ``tokens``,
         ``bucket``: the real and the computed length), one
         ``engine.install`` per finished prefill (``rid``, ``slot``,
-        ``plen``), ``engine.decode`` (``live``, ``slots``, ``kv_pages``),
+        ``plen``), ``engine.decode`` (``live``, ``slots``, ``kv_pages``,
+        ``sampled``: the live slots whose request samples),
         ``engine.sync`` (the transfer) and ``engine.deliver`` (``emitted``,
         ``finished``), which holds one ``engine.retire`` (``slot``) per
         finished slot.
@@ -731,6 +737,8 @@ class Engine:
                               plen=len(req.prompt)):
                         self._install(slot, st, logits[0])
                     self._alive.add(slot)
+                    if req.temperature > 0:
+                        self._sampling.add(slot)
                     del self._prefilling[slot]
             self._peak_slots = max(self._peak_slots, len(self._alive))
 
@@ -742,8 +750,10 @@ class Engine:
             count["decode_steps"] += 1
             count["decode_live_slot_rows"] += live
             count["decode_kv_pages"] += pages
+            sampled = len(self._sampling)
+            count["decode_sampled_steps"] += int(sampled > 0)
             with span("engine.decode", live=live, slots=scfg.max_slots,
-                      kv_pages=pages):
+                      kv_pages=pages, sampled=sampled):
                 self.cache, self.state, emitted, emit = self._decode(
                     self.params, self.cache, self.state)
             with span("engine.sync"):
@@ -827,6 +837,7 @@ class Engine:
         return its pages to the pool for reuse."""
         self.sched.evict(slot)
         self._alive.discard(slot)
+        self._sampling.discard(slot)
         if self._pager is not None:
             self.cache = _RETIRE(self.cache, slot, self._kv.trash_page)
             self._pager.release(self._slot_pages.pop(slot))

@@ -186,8 +186,10 @@ def make_slot_decode_step(cfg: ModelConfig, qcfg: QuantConfig | None,
     key splits once per step, so a request's k-th draw depends only on its
     own (seed, k) and never on batch composition.  ``temp == 0`` (the
     Request default) is exact greedy argmax through this same traced step;
-    the categorical adds zero host-transfer surfaces (the one-transfer
-    invariant is re-proved over this step by ``repro check``).
+    the sampling work runs only in a step where a live (emitting) slot
+    samples, so a greedy step sorts nothing.  The categorical adds zero
+    host-transfer surfaces (the one-transfer invariant is re-proved over
+    this step, branches included, by ``repro check``).
 
     ``use_pallas``/``interpret`` come from the engine's DeployPlan and route
     the vector-pos decode attention through the flash-decode kernel
@@ -207,7 +209,7 @@ def make_slot_decode_step(cfg: ModelConfig, qcfg: QuantConfig | None,
         draw_keys, next_keys = split_keys(state["key"])
         new_cur = sample_tokens(out["logits"][:, -1], draw_keys,
                                 state["temp"], state["top_k"],
-                                state["top_p"])
+                                state["top_p"], live=emit)
         new_state = {"cur": new_cur, "done": done, "counts": counts,
                      "budget": state["budget"], "eos": state["eos"],
                      "key": next_keys, "temp": state["temp"],

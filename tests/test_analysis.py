@@ -16,6 +16,7 @@ codes, the report JSON ↔ ``check_results --analysis`` round trip, and the
 ``launch.hlo_analysis.cost_summary`` list/dict compat shim.
 """
 import json
+import re
 
 import jax
 import jax.numpy as jnp
@@ -86,6 +87,49 @@ def test_decode_step_contains_device_rng():
     closed = jax.make_jaxpr(s["decode_fn"])(deployed, s["cache"], s["state"])
     text = str(closed)
     assert "random_split" in text and "random_bits" in text
+
+
+def _hlo_computations(text: str) -> tuple[str, dict[str, list[str]]]:
+    """Compiled HLO text as (entry name, {computation: instruction lines})."""
+    comps, entry, name = {}, None, None
+    for line in text.splitlines():
+        head = re.match(r"(ENTRY )?%(\S+) \(", line)
+        if head:
+            name = head.group(2)
+            comps[name] = []
+            if head.group(1):
+                entry = name
+        elif name is not None and line.strip() == "}":
+            name = None
+        elif name is not None:
+            comps[name].append(line)
+    return entry, comps
+
+
+def test_greedy_decode_step_sorts_only_inside_a_branch():
+    """Every ``sort`` of the compiled decode step (top-k's and top-p's)
+    lies in a conditional's branch: a step in which no live slot samples
+    takes the other branch and sorts nothing.  Walked from the entry
+    through every call but a conditional's branches."""
+    s, deployed = _decode_surfaces()
+    text = jax.jit(s["decode_fn"]).lower(
+        deployed, s["cache"], s["state"]).compile().as_text()
+    entry, comps = _hlo_computations(text)
+    callee = re.compile(r"(?:to_apply|calls|body|condition)=%([\w.\-]+)")
+
+    def has_sort(lines):
+        return any(re.search(r"\ssort\(", ln) for ln in lines)
+
+    assert sum(has_sort([ln]) for c in comps.values() for ln in c) >= 2
+    seen, stack = set(), [entry]
+    while stack:
+        name = stack.pop()
+        if name in seen:
+            continue
+        seen.add(name)
+        assert not has_sort(comps[name]), name
+        for ln in comps[name]:
+            stack.extend(callee.findall(ln))
 
 
 def test_injected_host_rng_draw_is_caught():

@@ -93,9 +93,26 @@ def test_counters_by_hand_and_reset(engine):
         "installs": 3, "decode_steps": 8,
         "decode_live_slot_rows": 2 * 4 + 1 * 4,
         "decode_kv_pages": 2 * 4 + 1 * 4,       # lengths 4-7: one page each
+        "decode_sampled_steps": 0,              # all greedy
         "tokens_emitted": 12, "retires": 3}
     engine.reset()
     assert set(counters(engine).values()) == {0}
+
+
+def test_sampled_steps_count_the_mix(engine):
+    """Greedy A (4 new), sampled B (2 new), greedy C (4 new), 3 prompt
+    tokens each, 2 slots: A and B decode in steps 1-2, B retires, C takes
+    its slot from step 3.  Only steps 1-2 hold a sampling slot."""
+    engine.reset()
+    out = engine.generate([
+        Request(prompt=[1, 2, 3], max_new_tokens=4),
+        Request(prompt=[4, 5, 6], max_new_tokens=2, temperature=0.9,
+                top_k=5, seed=3),
+        Request(prompt=[7, 8, 9], max_new_tokens=4)])
+    assert [len(t) for t in out] == [4, 2, 4]
+    c = counters(engine)
+    assert (c["decode_steps"], c["decode_sampled_steps"]) == (6, 2)
+    engine.reset()
 
 
 def _program_spans(logdir) -> list:
@@ -125,8 +142,10 @@ def session(engine, tmp_path_factory):
                       engine.sched._next_rid + len(prompts)))
     logdir = tmp_path_factory.mktemp("profile")
     jax.profiler.start_trace(str(logdir))
-    try:
-        engine.generate([Request(prompt=p, max_new_tokens=3)
+    try:                            # the 6-token prompt's request samples
+        engine.generate([Request(prompt=p, max_new_tokens=3,
+                                 temperature=0.8 if len(p) == 6 else 0.0,
+                                 seed=len(p))
                          for p in prompts])
     finally:
         jax.profiler.stop_trace()
@@ -181,6 +200,10 @@ def test_span_arguments_sum_to_the_counters(session):
     assert count("decode") == growth["decode_steps"]
     assert total("decode", "live") == growth["decode_live_slot_rows"]
     assert total("decode", "kv_pages") == growth["decode_kv_pages"]
+    # one sampling request of 3 new tokens: live in 3 decode steps
+    assert total("decode", "sampled") == 3
+    assert sum(1 for s in sp if s[0] == "engine.decode"
+               and s[3]["sampled"] > 0) == growth["decode_sampled_steps"] == 3
     assert {s[3]["slots"] for s in sp if s[0] == "engine.decode"} == {2}
     assert total("deliver", "emitted") == growth["tokens_emitted"] == 15
     assert total("deliver", "finished") == count("retire") \

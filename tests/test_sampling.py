@@ -3,7 +3,7 @@
 These are the serve engine's decoding semantics in isolation: truncation
 supports defined by VALUE thresholds (ties included, never sort order),
 ``temperature=0`` an exact argmax, and draws invariant under jit and under
-slot-vmap stacking — the property that makes per-request sampling immune
+slot stacking — the property that makes per-request sampling immune
 to batch composition (tests/test_serve_scheduler.py proves the end-to-end
 version through the engine).
 
@@ -80,7 +80,13 @@ def test_top_k_support_matches_reference(data):
         np.float32)
     k = data.draw(st.integers(0, v + 2), label="k")
     got = np.asarray(top_k_mask(jnp.asarray(logits), k))
-    want = np.where(np_top_k_support(logits, k), logits, _NEG_INF)
+    # XLA compares float32 subnormals as zero (it flushes them), NumPy does
+    # not: logits [0.0, 1.29e-42] at k = 1 tie on the device, which keeps
+    # both.  The reference's support is taken over the values as the device
+    # compares them; the kept values themselves pass through unflushed.
+    flushed = np.where(np.abs(logits) < np.finfo(np.float32).tiny,
+                       np.float32(0), logits)
+    want = np.where(np_top_k_support(flushed, k), logits, _NEG_INF)
     np.testing.assert_array_equal(got, want)
 
 
@@ -210,7 +216,7 @@ def test_draws_stay_inside_truncated_support():
 
 
 # ---------------------------------------------------------------------------
-# Invariance: jit and slot-vmap stacking (the engine's actual call shapes)
+# Invariance: jit and slot stacking (the engine's actual call shapes)
 # ---------------------------------------------------------------------------
 
 @settings(max_examples=25, deadline=None)
@@ -233,7 +239,7 @@ def test_draw_invariant_under_jit(data):
 @settings(max_examples=25, deadline=None)
 @given(st.data())
 def test_draw_invariant_under_slot_vmap(data):
-    """Stacking S slots into one vmapped call (what the decode step does)
+    """Stacking S slots into one batched call (what the decode step does)
     draws exactly what S independent per-slot calls would — the property
     that makes batch composition invisible to any one request."""
     S = data.draw(st.integers(1, 5), label="slots")
@@ -260,6 +266,84 @@ def test_draw_invariant_under_slot_vmap(data):
     solo = [sample_token(logits[i], keys[i], temps[i], ks[i], ps[i])
             for i in range(S)]
     assert [int(t) for t in stacked] == [int(t) for t in solo]
+
+
+# ---------------------------------------------------------------------------
+# The batched sampler against the per-slot one it replaced
+# ---------------------------------------------------------------------------
+
+def _oracle_sample_token(logits, key, temperature, top_k=0, top_p=1.0):
+    """The one-row sampler as the slot-decode step ran it under vmap before
+    the sampled work moved under a cond: both masks and the categorical on
+    every row, then a select of the argmax for greedy rows."""
+    temperature = jnp.asarray(temperature, jnp.float32)
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    scaled = logits.astype(jnp.float32) / jnp.maximum(temperature, 1e-6)
+    masked = top_p_mask(top_k_mask(scaled, top_k), top_p)
+    drawn = jax.random.categorical(key, masked, axis=-1).astype(jnp.int32)
+    return jnp.where(temperature > 0.0, drawn, greedy)
+
+
+oracle_sample_tokens = jax.jit(jax.vmap(_oracle_sample_token))
+
+_S, _V = 6, 50
+_LIVE = [True] * _S
+_GREEDY = dict(temp=[0.0] * _S, k=[0] * _S, p=[1.0] * _S, live=_LIVE)
+#: per-slot (temperature, top_k, top_p, live) pools; dead rows hold the
+#: stale parameters of the request that last held the slot
+POOLS = {
+    "all_greedy": _GREEDY,
+    "one_live_sampled": {**_GREEDY, "temp": [0, 0, 0.9, 0, 0, 0],
+                         "k": [0, 0, 7, 0, 0, 0], "p": [1, 1, .8, 1, 1, 1]},
+    "top_k_only": {**_GREEDY, "temp": [1.3, 0, 0.8, 1.0, 0, 2.0],
+                   "k": [3, 5, 1, 0, 9, 40]},
+    "top_p_only": {**_GREEDY, "temp": [1.1, 0.6, 0, 1.0, 2.0, 0.9],
+                   "p": [0.5, 0.9, 0.3, 1.0, 0.95, 0.2]},
+    "temperature_only": {**_GREEDY, "temp": [0.7, 1.0, 0, 1.5, 3.0, 0.2]},
+    "dead_stale_all_greedy": {
+        "temp": [0, 1.2, 0, 0.9, 0, 0], "k": [0, 5, 0, 3, 0, 0],
+        "p": [1, 0.5, 1, 0.7, 1, 1],
+        "live": [True, False, True, False, True, True]},
+    "dead_stale_live_sampler": {
+        "temp": [0, 1.2, 1.4, 0.9, 0, 0], "k": [0, 5, 0, 3, 0, 0],
+        "p": [1, 0.5, 1, 0.7, 1, 1],
+        "live": [True, False, True, False, True, True]},
+}
+
+
+@pytest.mark.parametrize("pool", sorted(POOLS))
+def test_sample_tokens_matches_per_slot_oracle(pool):
+    """On every live row the batched sampler returns what the per-slot
+    sampler returned, bit for bit: greedy rows their argmax, sampled rows
+    the same keyed draw from the same scaled, masked logits."""
+    cfg = POOLS[pool]
+    logits = 3.0 * jax.random.normal(keyed(17), (_S, _V), jnp.float32)
+    keys = jnp.stack([keyed(1000 + i) for i in range(_S)])
+    temp = jnp.asarray(cfg["temp"], jnp.float32)
+    k = jnp.asarray(cfg["k"], jnp.int32)
+    p = jnp.asarray(cfg["p"], jnp.float32)
+    live = np.asarray(cfg["live"])
+    got = np.asarray(jax.jit(sample_tokens)(logits, keys, temp, k, p,
+                                            live=jnp.asarray(live)))
+    want = np.asarray(oracle_sample_tokens(logits, keys, temp, k, p))
+    np.testing.assert_array_equal(got[live], want[live])
+    greedy = np.asarray(jnp.argmax(logits, axis=-1))
+    drawing = live & (np.asarray(temp) > 0)
+    np.testing.assert_array_equal(got[~drawing & live], greedy[~drawing & live])
+    if drawing.sum() > 1:             # the sampled branch really ran
+        assert (got[drawing] != greedy[drawing]).any()
+
+
+@pytest.mark.parametrize("temp,k,p", [(0.0, 0, 1.0), (0.8, 0, 1.0),
+                                      (1.1, 5, 1.0), (0.7, 0, 0.6),
+                                      (1.3, 4, 0.8)])
+def test_sample_token_matches_per_slot_oracle(temp, k, p):
+    """The install's one-row draw is the batched sampler on one live row."""
+    logits = 2.0 * jax.random.normal(keyed(5), (_V,), jnp.float32)
+    for seed in range(8):
+        got = sample_token(logits, keyed(seed), temp, k, p)
+        want = _oracle_sample_token(logits, keyed(seed), temp, k, p)
+        assert int(got) == int(want), seed
 
 
 def test_split_keys_matches_per_slot_splits():
