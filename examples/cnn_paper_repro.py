@@ -7,7 +7,7 @@ Default: the end-to-end pipeline on the paper CNN — train an FP teacher,
 heuristic PTQ (calibrate + MMSE init), QFT recovery, int4 export — via
 repro.pipeline (same path as ``python -m repro quantize --config paper_cnn``).
 ``--tables`` walks the paper's full Table 2 → Table 1 story with the exact
-benchmark grid (benchmarks/paper_figures.py).
+benchmark grid (paper/figures.py).
 """
 import argparse
 
@@ -15,7 +15,7 @@ from repro.pipeline import PipelineConfig, run_pipeline
 
 
 def run_tables():
-    from benchmarks.paper_figures import table1_qft_vs_baselines, table2_no_qft
+    from paper.figures import table1_qft_vs_baselines, table2_no_qft
     print("— Table 2 (heuristics only, no QFT) —")
     for r in table2_no_qft():
         print(f"  {r['setting']:>22s}: acc {r['acc']:.3f} "

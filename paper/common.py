@@ -1,19 +1,17 @@
-"""Shared fixtures for the paper-claim benchmarks.
+"""Shared fixtures for the paper's figure and table reproduction.
 
 - a trained tiny CNN teacher on a synthetic separable classification task
   (the paper's own experimental setting at CPU scale; accuracy is exact);
 - a tiny LM teacher + calibration stream (degradation measured as
   normalized-L2 distillation loss / top-1 next-token agreement with the FP
   teacher — see DESIGN.md §9.3).
-Fixtures are cached under benchmarks/results/.
+Fixtures are cached under paper/results/.
 """
 from __future__ import annotations
 
 import functools
 import os
 import pathlib
-import subprocess
-import time
 
 import jax
 import jax.numpy as jnp
@@ -28,35 +26,6 @@ RESULTS = pathlib.Path(__file__).resolve().parent / "results"
 RESULTS.mkdir(parents=True, exist_ok=True)
 
 FAST = os.environ.get("REPRO_BENCH_FAST", "1") == "1"
-
-
-def git_sha(short: bool = True) -> str:
-    """Current commit sha (keys BENCH_history.jsonl rows); 'unknown' when
-    not running inside a git checkout."""
-    cmd = ["git", "rev-parse"] + (["--short"] if short else []) + ["HEAD"]
-    try:
-        out = subprocess.run(cmd,
-                             cwd=pathlib.Path(__file__).resolve().parent,
-                             check=True, capture_output=True, text=True,
-                             timeout=10)
-        return out.stdout.strip() or "unknown"
-    except Exception:  # noqa: BLE001 — no git, detached worktree, etc.
-        return "unknown"
-
-
-def percentile_steps(values, q: float) -> int:
-    """Nearest-rank percentile of integer step counts.
-
-    Deterministic and interpolation-free (numpy changed its default
-    interpolation across versions; history rows must not depend on it).
-    """
-    if not 0.0 < q <= 1.0:
-        raise ValueError(f"q must be in (0, 1], got {q}")
-    vs = sorted(values)
-    if not vs:
-        raise ValueError("percentile of empty sequence")
-    rank = max(1, -(-round(q * 100) * len(vs) // 100))  # ceil(q*n), 1-based
-    return int(vs[rank - 1])
 
 
 # ---------------------------------------------------------------- CNN fixture
@@ -174,12 +143,3 @@ def lm_degradation(student, qcfg, batches=4):
         agree.append(float(jnp.mean(
             jnp.argmax(so["logits"], -1) == jnp.argmax(to["logits"], -1))))
     return float(np.mean(losses)), float(np.mean(agree))
-
-
-def timed(fn, *args, reps=3):
-    fn(*args)
-    t0 = time.perf_counter()  # qft: noqa[QFT005] timed() is the sanctioned wall-clock helper
-    for _ in range(reps):
-        out = fn(*args)
-    jax.block_until_ready(out)
-    return (time.perf_counter() - t0) / reps * 1e6     # µs  # qft: noqa[QFT005] sanctioned wall_s column

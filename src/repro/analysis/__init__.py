@@ -11,9 +11,7 @@ Two layers over one report schema:
 - **lint** — repo-specific AST rules QFT001..QFT006 with
   ``# qft: noqa[RULE]`` suppression.
 
-``runner.run_check`` composes both into a :class:`report.Report`;
-``benchmarks/check_results.py --analysis`` re-validates the JSON artifact
-in CI with stdlib only.
+``runner.run_check`` composes both into a :class:`report.Report`.
 """
 from .lint import RULES, lint_paths, lint_source           # noqa: F401
 from .report import SCHEMA_VERSION, Diagnostic, Report     # noqa: F401

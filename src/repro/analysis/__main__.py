@@ -20,7 +20,7 @@ def main(argv: list[str] | None = None) -> int:
         description="QFT lint rules (AST layer only; no jax required)")
     ap.add_argument("--paths", nargs="*", default=None,
                     help="repo-relative files/dirs (default: src/repro "
-                         "benchmarks)")
+                         "paper)")
     ap.add_argument("-v", "--verbose", action="store_true")
     args = ap.parse_args(argv)
 

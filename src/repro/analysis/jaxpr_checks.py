@@ -604,7 +604,7 @@ def check_train_step(arch: str, cfg, qcfg, plan) -> list[Diagnostic]:
 
 
 def _small_train_batch(cfg, B: int = 2, S: int = 32) -> dict:
-    """registry.input_specs geometry at trace-friendly size."""
+    """A train batch of every model input at trace-friendly size."""
     i32 = jnp.int32
     tok = lambda b, s: jax.ShapeDtypeStruct((b, s), i32)  # noqa: E731
     if cfg.family == "vlm":

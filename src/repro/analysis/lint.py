@@ -22,9 +22,8 @@ QFT003  host sync inside jitted serve/decode code: ``jax.device_get``,
         core/sampling.py).
 QFT004  hardcoded ``interpret=True/False`` instead of the backend
         auto-select ``None`` (``kernels.quant_matmul.default_interpret``).
-QFT005  wall-clock or unseeded randomness in ``benchmarks/`` outside the
-        sanctioned ``wall_s`` columns: bench rows are step-counted and
-        machine-independent by design.
+QFT005  wall-clock or unseeded randomness in ``paper/``: the paper's
+        figures and tables must reproduce exactly from their seeds.
 QFT006  mutable default (``[]``/``{}``/``set()``/``list()``/``dict()``) on
         a dataclass field — shared-state bugs in frozen config objects.
 
@@ -72,8 +71,8 @@ RULES: dict[str, Rule] = {
     "QFT003": Rule("QFT003", "host sync inside jitted serve/decode code",
                    _under("src/repro/serve/", "src/repro/train/")),
     "QFT004": Rule("QFT004", "hardcoded interpret= instead of auto-select None"),
-    "QFT005": Rule("QFT005", "wall-clock / unseeded randomness in benchmarks",
-                   _under("benchmarks/")),
+    "QFT005": Rule("QFT005", "wall-clock / unseeded randomness in paper/",
+                   _under("paper/")),
     "QFT006": Rule("QFT006", "mutable default on a dataclass field"),
 }
 
@@ -299,14 +298,14 @@ class _Visitor(ast.NodeVisitor):
             tail2 = tuple(parts[-2:]) if len(parts) >= 2 else None
             if tail2 in _WALL_CLOCK:
                 self._emit("QFT005", node,
-                           f"wall-clock `{dotted}` in benchmarks — rows are "
-                           "step-counted; confine wall time to wall_s columns")
+                           f"wall-clock `{dotted}` in paper/ — a reproduction "
+                           "must not depend on the machine's clock")
             elif (len(parts) >= 2 and parts[0] in ("np", "numpy", "random")
                   and parts[-2] == "random"
                   and parts[-1] in _UNSEEDED_RANDOM):
                 # jax.random.* is exempt: every draw takes an explicit key
                 self._emit("QFT005", node,
-                           f"unseeded `{dotted}` in benchmarks — draw from a "
+                           f"unseeded `{dotted}` in paper/ — draw from a "
                            "seeded RandomState/default_rng")
 
         self.generic_visit(node)
@@ -340,7 +339,7 @@ def lint_source(src: str, path: str,
     return out
 
 
-DEFAULT_LINT_ROOTS = ("src/repro", "benchmarks")
+DEFAULT_LINT_ROOTS = ("src/repro", "paper")
 
 
 def iter_py_files(root: Path, paths: Iterable[str]) -> list[Path]:

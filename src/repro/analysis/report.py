@@ -1,13 +1,12 @@
 """Diagnostic / Report containers shared by both analyzer layers.
 
 Everything the trace-time analyzer (jaxpr_checks) and the AST lint pass
-(lint) produce funnels into one `Report` so the CLI, the JSON artifact,
-and `benchmarks/check_results.py --analysis` all read a single schema.
+(lint) produce funnels into one `Report` so the CLI and the JSON artifact
+read a single schema.
 
 The JSON schema (``SCHEMA_VERSION``) is deliberately flat: a summary dict
-plus one list of diagnostic records.  `check_results.py` is stdlib-only
-and re-validates this shape without importing repro, so keep the
-serialized form primitive (str/int/None/list/dict only).
+plus one list of diagnostic records.  Keep the serialized form primitive
+(str/int/None/list/dict only) so any JSON reader can consume it.
 """
 from __future__ import annotations
 

@@ -31,7 +31,7 @@ def run_check(configs: list[str] | None = None,
     """Run the requested layers and return the combined Report.
 
     ``configs=None`` means every registry arch; ``lint_paths_arg=None``
-    means the default roots (src/repro + benchmarks).  ``trace=False``
+    means the default roots (src/repro + paper).  ``trace=False``
     skips the jaxpr layer (lint-only mode — fast, no jax import cost in
     the hot path of pre-commit usage).
     """

@@ -1,2 +1,1 @@
-from .registry import (ARCH_IDS, SHAPES, get_config, get_module, input_specs,
-                       skip_reason, cell_list, ShapeSpec)
+from .registry import ARCH_IDS, get_config, get_module

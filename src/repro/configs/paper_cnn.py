@@ -2,7 +2,7 @@
 
 A small conv backbone (lax.conv) + classifier used to validate the paper's
 figure/table-level claims (MMSE granularity, CLE, QFT recovery) in the exact
-layer type the paper studies. See models/cnn.py and benchmarks/.
+layer type the paper studies. See models/cnn.py and paper/.
 """
 from ..models.cnn import CNNConfig
 

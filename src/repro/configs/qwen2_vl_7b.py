@@ -1,7 +1,7 @@
 """Qwen2-VL-7B [arXiv:2409.12191]: 28L d=3584 28H (GQA kv=4) ff=18944 V=152064.
 
 M-RoPE (sections 16/24/24 on the half head-dim), dynamic-resolution vision
-frontend STUBBED: input_specs() feeds precomputed patch embeddings [B,S_img,d].
+frontend STUBBED: the model takes precomputed patch embeddings [B,S_img,d].
 """
 import dataclasses
 from ..models.config import ModelConfig

@@ -1,6 +1,6 @@
 """SeamlessM4T-medium [arXiv:2308.11596]: enc-dec 12L+12L d=1024 16H ff=4096 V=256206.
 
-Multimodal (speech/text) — audio frontend STUBBED: input_specs() provides
+Multimodal (speech/text) — audio frontend STUBBED: the model takes
 precomputed frame embeddings [B, S_enc, d]. GELU MLP (conformer-lite backbone
 approximated as a standard transformer per pool spec). Decoder: 12L causal +
 cross-attention. Vocab padded to 256256 for TP16 (DESIGN.md §5).

@@ -8,7 +8,7 @@
   solved by alternating row/column projections.
 
 All routines are pure jnp + lax.fori_loop → jit/vmap-able, used both at
-initialization time and inside benchmarks.
+initialization time and by the paper reproduction (paper/).
 """
 from __future__ import annotations
 

@@ -25,10 +25,9 @@ groups) — callers (kernels.ops.pallas_tiles_ok) fall back to the XLA
 reference otherwise.  Compiled for the TPU, a group must also be a whole
 number of 128-lane vregs (g % 128 == 0); interpret mode takes any g.
 
-``variant="dequant"`` keeps the original dequantize-then-f32-dot body as a
-benchmark baseline (benchmarks/run.py measures int8dot vs dequant in
-deterministic interpret-mode work units); production always wants the
-default ``"int8dot"``.
+``variant="dequant"`` keeps the original dequantize-then-f32-dot body; its
+one use is the analyzer tests' planted f32-dequant fault.  Production always
+wants the default ``"int8dot"``.
 
 Tiling: grid (M/bm, N/bn, K/bk); x tile [bm, bk] and packed-weight tile
 [bk/2, bn] are staged into VMEM per step; f32 accumulation in a VMEM scratch
@@ -174,7 +173,7 @@ def quant_matmul(x: jax.Array, qw: jax.Array, s_wl: jax.Array,
     (production shapes are MXU-aligned by construction).
     interpret=None auto-selects by backend; True forces the CPU interpreter.
     ``variant``: "int8dot" (default — integer weight operand, hoisted scales)
-    or "dequant" (the pre-fusion f32-dequant baseline, benchmarks only).
+    or "dequant" (the pre-fusion f32-dequant body, analyzer tests only).
     """
     if interpret is None:
         interpret = default_interpret()

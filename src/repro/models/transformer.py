@@ -331,7 +331,7 @@ def forward(params: Params, cfg: ModelConfig, qcfg: QuantConfig | None,
     else:
         base = 0
     # per-slot serving caches carry a [B] position vector — one offset per
-    # slot — instead of the scalar the static train/dryrun paths use
+    # slot — instead of the scalar the static train/prefill paths use
     off = base[:, None] if getattr(base, "ndim", 0) == 1 else base
     if "positions" in batch:
         positions = batch["positions"]

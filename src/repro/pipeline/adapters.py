@@ -88,7 +88,7 @@ class TransformerAdapter:
     # ------------------------------------------------------------- fixtures
     def _augment(self, batch: dict) -> dict:
         """Stub modality inputs for VLM / enc-dec families (precomputed-
-        embedding frontends, per the registry's input_specs convention)."""
+        embedding frontends, as the config modules state)."""
         batch = {k: jnp.asarray(v) for k, v in batch.items()}
         fam, d = self.cfg.family, self.cfg.d_model
         B, S = batch["tokens"].shape

@@ -106,13 +106,12 @@ def build_parser() -> argparse.ArgumentParser:
                    help="skip the AST lint layer")
     c.add_argument("--paths", nargs="*", default=None,
                    help="files/dirs to lint, repo-root-relative "
-                        "(default: src/repro benchmarks)")
+                        "(default: src/repro paper)")
     c.add_argument("--prefill-budget", type=int, default=None,
                    help="fail if a config's prefill recompile surface "
                         "exceeds this many distinct programs")
     c.add_argument("--json", default=None, metavar="PATH",
-                   help="write the machine-readable report "
-                        "(benchmarks/check_results.py --analysis)")
+                   help="write the machine-readable report")
     c.add_argument("-v", "--verbose", action="store_true",
                    help="print info/skip diagnostics, not just problems")
     return ap

@@ -115,7 +115,7 @@ def plan_from_artifact(exported: Params) -> QuantPlan | None:
     if isinstance(arr, (jax.core.Tracer, jax.ShapeDtypeStruct)):
         # inside jit/eval_shape the leaf is abstract and cannot be decoded —
         # not corruption; callers tracing deploy_view should resolve the
-        # DeployPlan eagerly outside the trace (see launch/dryrun.py)
+        # DeployPlan eagerly outside the trace
         return None
     try:
         return plan_from_array(arr)

@@ -8,9 +8,6 @@ Baseline policy (paper-faithful system, before §Perf hillclimbing):
 - Megatron TP: qkv/up col-parallel, o/down row-parallel, vocab-sharded
   embed+head; experts EP-sharded on `model`; FSDP on `data` for weights,
   optimizer state and the (frozen) teacher.
-- decode: batch→DP; KV cache sequence-sharded over `model` when kv-heads
-  don't divide TP (flash-decoding combine is emitted by GSPMD); SSM state
-  head-sharded.
 - quant-DoF vectors (log_s*, streams, norms, biases) replicated — they are
   O(channels) and train data-parallel.
 """
@@ -60,7 +57,6 @@ class ShardingPolicy:
     tp: str = "model"
     fsdp: str | None = "data"                # None → pure DP (no ZeRO)
     fsdp_teacher: bool = True
-    seq_shard_cache: bool = True             # decode KV seq over tp if heads<tp
     remat: bool = True
 
 
@@ -154,41 +150,3 @@ def batch_shardings(batch_struct, mesh: Mesh, pol: ShardingPolicy):
         return NamedSharding(mesh, P(*([b] + [None] * (len(leaf.shape) - 1))))
 
     return jax.tree_util.tree_map_with_path(one, batch_struct)
-
-
-def cache_shardings(cache_struct, cfg: ModelConfig, mesh: Mesh,
-                    pol: ShardingPolicy):
-    """Decode/prefill caches. KV: [L, B, S, Hkv, hd]; MLA: [L, B, S, lat];
-    SSM state: [L, B, H, P, N]; conv: [L, B, k, cd]."""
-    tp, dp = pol.tp, pol.dp
-
-    def one(path, leaf):
-        keys = _last_keys(path)
-        name = keys[-1]
-        shape = leaf.shape
-        if name == "pos":
-            return NamedSharding(mesh, P())
-        if name in ("k", "v"):           # [L, B, S, Hkv, hd]
-            b = div_axes(shape[1], dp, mesh)
-            h = div_axes(shape[3], tp, mesh)
-            if h is not None:
-                return NamedSharding(mesh, P(None, b, None, h, None))
-            s = div_axes(shape[2], tp, mesh) if pol.seq_shard_cache else None
-            return NamedSharding(mesh, P(None, b, s, None, None))
-        if name in ("ckv", "kr"):        # [L, B, S, lat]
-            b = div_axes(shape[1], dp, mesh)
-            s = div_axes(shape[2], tp, mesh) if pol.seq_shard_cache else None
-            return NamedSharding(mesh, P(None, b, s, None))
-        if name == "ssm_state":          # [..., B, H, P, N]
-            nd = len(shape)
-            b = div_axes(shape[-4], dp, mesh)
-            h = div_axes(shape[-3], tp, mesh)
-            return NamedSharding(mesh, P(*([None] * (nd - 4)), b, h, None, None))
-        if name == "conv_state":         # [..., B, k, cd]
-            nd = len(shape)
-            b = div_axes(shape[-3], dp, mesh)
-            c = div_axes(shape[-1], tp, mesh)
-            return NamedSharding(mesh, P(*([None] * (nd - 3)), b, None, c))
-        return NamedSharding(mesh, P(*([None] * len(shape))))
-
-    return jax.tree_util.tree_map_with_path(one, cache_struct)

@@ -1,5 +1,5 @@
-"""The QFT step functions — the units lowered by launch/dryrun and driven by
-train/qft_trainer.
+"""The QFT step functions — driven by train/qft_trainer and the serve
+engine.
 
 train_step  = teacher forward (FP, stop-grad) + student forward (fake-quant,
               offline subgraph inside) + backbone-L2 distillation + Adam.
@@ -142,19 +142,6 @@ def make_bucketed_prefill_step(cfg: ModelConfig, qcfg: QuantConfig | None,
         return logits, new_cache
 
     return prefill_step
-
-
-def make_decode_step(cfg: ModelConfig, qcfg: QuantConfig | None, plan=None):
-    """decode_step(params, cache, batch{tokens:[B,1]}) -> (logits, cache).
-
-    Greedy next-token; the cache is donated by callers (serve engine, dryrun).
-    """
-
-    def decode_step(params, cache, batch):
-        out = forward(params, cfg, qcfg, batch, cache=cache, plan=plan)
-        return out["logits"][:, -1], out["cache"]
-
-    return decode_step
 
 
 # ---------------------------------------------------------------------------

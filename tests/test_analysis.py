@@ -12,8 +12,7 @@ criteria):
 - a hardcoded ``interpret=True`` → QFT004;
 
 plus per-rule lint coverage with ``# qft: noqa`` suppression, CLI exit
-codes, the report JSON ↔ ``check_results --analysis`` round trip, and the
-``launch.hlo_analysis.cost_summary`` list/dict compat shim.
+codes, and the report JSON's schema and summary.
 """
 import json
 import re
@@ -21,18 +20,14 @@ import re
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
-from benchmarks.check_results import check_analysis
 from repro.analysis.jaxpr_checks import (callback_count,
                                          dequant_dot_violations,
                                          integer_dot_count,
                                          transfer_surfaces)
 from repro.analysis.lint import lint_source
-from repro.analysis.report import Diagnostic, Report
 from repro.core import permissive
 from repro.kernels.quant_matmul import quant_matmul
-from repro.launch.hlo_analysis import cost_summary
 from repro.models import ModelConfig
 from repro.pipeline.cli import main as cli_main
 from repro.serve.deploy import abstract_deploy_surfaces
@@ -333,10 +328,10 @@ def test_qft005_wall_clock_and_unseeded_random():
            "x = np.random.rand(4)\n"
            "k = jax.random.normal(key, (4,))\n"     # keyed: exempt
            "r = np.random.RandomState(0).rand(4)\n")  # seeded: exempt
-    diags = lint_source(src, "benchmarks/foo.py")
+    diags = lint_source(src, "paper/foo.py")
     assert _ids(diags) == ["QFT005", "QFT005"]
     assert [d.line for d in diags] == [1, 2]
-    # outside benchmarks/ the rule does not apply
+    # outside paper/ the rule does not apply
     assert lint_source(src, "src/repro/train/foo.py") == []
 
 
@@ -384,62 +379,26 @@ def test_check_cli_injected_violation_exits_nonzero(tmp_path, capsys):
 
 
 def test_check_cli_json_report_validates(tmp_path, capsys):
+    """The JSON report's summary counts its body's diagnostics by severity,
+    on a clean tree and on one with a planted error."""
+    bad = tmp_path / "bad.py"
+    bad.write_text("y = quant_matmul(x, q, s, interpret=True)\n")
     report_path = tmp_path / "ANALYSIS_report.json"
-    rc = cli_main(["check", "--lint-only", "--paths", "src/repro/analysis",
-                   "--json", str(report_path)])
-    capsys.readouterr()
-    assert rc == 0
-    assert check_analysis(report_path) == []
-    rep = json.loads(report_path.read_text())
-    assert rep["schema"] == 1 and rep["tool"] == "repro-check"
-
-
-def test_check_analysis_rejects_error_reports(tmp_path):
-    r = Report()
-    r.add(Diagnostic(check="QFT004", message="boom", file="x.py", line=3))
-    p = tmp_path / "bad_report.json"
-    r.write_json(p)
-    errs = check_analysis(p)
-    assert errs and any("QFT004" in e for e in errs)
-
-
-def test_check_analysis_rejects_inconsistent_summary(tmp_path):
-    rep = Report().to_json()
-    rep["summary"]["errors"] = 5                   # lies about its own body
-    p = tmp_path / "lying_report.json"
-    p.write_text(json.dumps(rep))
-    assert check_analysis(p)
+    for paths, want_errors in ((["src/repro/analysis"], 0), ([str(bad)], 1)):
+        rc = cli_main(["check", "--lint-only", "--paths", *paths,
+                       "--json", str(report_path)])
+        capsys.readouterr()
+        assert rc == want_errors
+        rep = json.loads(report_path.read_text())
+        assert rep["schema"] == 1 and rep["tool"] == "repro-check"
+        for key, sev in (("errors", "error"), ("warnings", "warning"),
+                         ("infos", "info"), ("skips", "skip")):
+            assert rep["summary"][key] == sum(
+                d["severity"] == sev for d in rep["diagnostics"]), key
+        assert rep["summary"]["errors"] == want_errors
 
 
 def test_check_cli_unknown_config_is_usage_error(capsys):
     rc = cli_main(["check", "--config", "not-a-config", "--trace-only"])
     capsys.readouterr()
     assert rc == 2
-
-
-# ---------------------------------------------------------------------------
-# launch.hlo_analysis.cost_summary
-# ---------------------------------------------------------------------------
-
-class _Compiled:
-    def __init__(self, ca):
-        self._ca = ca
-
-    def cost_analysis(self):
-        return self._ca
-
-
-def test_cost_summary_dict_shaped():
-    got = cost_summary(_Compiled({"flops": 12.0, "bytes accessed": 34.0}))
-    assert got == {"flops": 12.0, "bytes": 34.0}
-
-
-def test_cost_summary_real_lowering():
-    """End-to-end on a real compiled step (CPU): keys exist and flops are
-    positive for a matmul."""
-    fn = jax.jit(lambda a, b: a @ b)
-    x = jnp.ones((16, 16), jnp.float32)
-    compiled = fn.lower(x, x).compile()
-    got = cost_summary(compiled)
-    assert set(got) == {"flops", "bytes"}
-    assert got["flops"] > 0

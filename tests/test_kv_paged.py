@@ -17,7 +17,8 @@ The contract, layered from structure up to behavior:
   emitted token of every request matches the monolithic f32 engine
   exactly (it is drawn from the f32 prefill logits in both layouts);
   eviction returns every page (stats-visible) and admission is gated by
-  free pages, not just free slots.
+  free pages, not just free slots; a paged int8 pool that holds no more
+  bytes than a monolithic one runs strictly more slots at once.
 - **Bugfix satellites**: bucketed pad-and-mask prefill ≡ exact-length
   prefill; Engine construction refuses an MoE capacity_factor that could
   silently drop decode tokens; SMOKE configs re-derive their padded
@@ -290,6 +291,38 @@ def test_admission_gated_by_free_pages_not_just_slots():
     # the pool bound is enforced at submit for impossible requests
     with pytest.raises(ValueError, match="kv_pages"):
         engine.submit(Request(prompt=list(range(1, 40)), max_new_tokens=20))
+
+
+@pytest.mark.parametrize("family", sorted(CONFIGS))
+def test_paged_pool_holds_more_slots_in_monolithic_bytes(family):
+    """The int8 page pool buys concurrency: under one burst of 16 requests,
+    8 paged slots whose pool holds the token capacity of 4 monolithic slots
+    (and no more slot-cache bytes) run strictly more slots at once, at
+    fewer peak live bytes per active slot."""
+    cfg = CONFIGS[family]
+    params = init_model(jax.random.PRNGKey(0), cfg, permissive())
+    mono_slots, max_len, page = 4, 64, 16
+    engines = {
+        "monolithic": Engine(cfg, permissive(), params, ServeConfig(
+            max_slots=mono_slots, max_len=max_len, prefill_chunk=8,
+            kv_mode="monolithic")),
+        "paged": Engine(cfg, permissive(), params, ServeConfig(
+            max_slots=2 * mono_slots, max_len=max_len, prefill_chunk=8,
+            kv_mode="paged", kv_page_size=page,
+            kv_pages=mono_slots * max_len // page)),
+    }
+    rng = np.random.default_rng(0)
+    burst = [Request(prompt=rng.integers(1, cfg.vocab, 5).tolist(),
+                     max_new_tokens=10) for _ in range(16)]
+    stats = {}
+    for mode, engine in engines.items():
+        assert all(len(t) == 10 for t in engine.generate(burst))
+        stats[mode] = engine.stats()
+    mono, paged = stats["monolithic"], stats["paged"]
+    assert paged["slot_cache_bytes"] <= mono["slot_cache_bytes"]
+    assert paged["peak_slots_active"] > mono["peak_slots_active"]
+    assert (paged["peak_live_bytes"] / paged["peak_slots_active"]
+            < mono["peak_live_bytes"] / mono["peak_slots_active"])
 
 
 def test_moe_capacity_footgun_refused_at_construction():

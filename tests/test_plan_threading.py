@@ -200,10 +200,10 @@ def test_plan_view_scoping():
 
 
 def test_mesh_context_provides_ambient_mesh():
-    """Regression (ROADMAP dryrun item): entering a launch.mesh mesh with
-    jax.set_mesh must install an ambient mesh so constrain_act's
-    bare-PartitionSpec sharding constraint traces — a missing ambient mesh
-    broke every dryrun prefill/decode cell."""
+    """Regression: entering a launch.mesh mesh with jax.set_mesh must
+    install an ambient mesh so constrain_act's bare-PartitionSpec sharding
+    constraint traces — a missing ambient mesh broke every sharded
+    prefill/decode lowering."""
     from jax.sharding import PartitionSpec as P
     from repro.launch.mesh import make_host_mesh
     mesh = make_host_mesh()

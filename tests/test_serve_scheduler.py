@@ -17,8 +17,14 @@ sampled request's tokens are bit-identical solo vs static-batch vs
 interleaved, the same seed twice reproduces, different seeds diverge
 (non-vacuity), and eos still stops a sampled stream early in any
 composition.  The token-streaming consumer API (Engine.stream /
-submit(on_token=...)) is covered at the end: emission order, ownership
+submit(on_token=...)) is covered next: emission order, ownership
 transfer, bounded memory.
+
+Then open-loop arrivals: seeded poisson, bursty and long-tailed traces
+submitted at their arrival steps while the engine steps, checked against
+solo runs (greedy, sampled and on the paged decode kernel), and continuous
+refill against static waves of ``max_slots``; then ``Engine.stats()``
+accounting.
 """
 import dataclasses
 import functools
@@ -33,7 +39,8 @@ from repro.core import permissive
 from repro.models import ModelConfig, init_model
 from repro.models.config import MoEConfig, SSMConfig
 from repro.serve.deploy import init_slot_cache, make_deploy_plan
-from repro.serve.engine import Engine, Request, Scheduler, ServeConfig
+from repro.serve.engine import (STEP_COUNTERS, Engine, Request, Scheduler,
+                                ServeConfig)
 
 CONFIGS = {
     "dense": ModelConfig(name="t", family="dense", n_layers=2, d_model=32,
@@ -338,6 +345,188 @@ def test_serve_config_rejects_nonsense():
         engine_for("dense", max_slots=0)
     # legacy spelling still accepted
     assert ServeConfig(slots=6).max_slots == 6
+
+
+# ---------------------------------------------------------------------------
+# Open-loop arrivals: seeded traces submitted while the engine steps
+# ---------------------------------------------------------------------------
+
+ARRIVAL_KINDS = ("poisson", "bursty", "longtail")
+
+
+def arrival_trace(kind: str, sampled: bool = False, n: int = 16,
+                  max_prompt: int = 24, max_new: int = 12):
+    """Seeded ``(arrival step, Request)`` pairs, arrivals non-decreasing.
+
+    ``poisson``: Poisson gaps between arrivals.  ``bursty``: on/off, bursts
+    of 2-5 requests on one step with 4-10 idle steps between.
+    ``longtail``: Poisson arrivals with lognormal prompt and output lengths
+    clipped to the limits.  ``sampled``: every third request samples
+    (seeded ``temperature`` 0.8, ``top_p`` 0.9); the rest are greedy."""
+    rng = np.random.default_rng(ARRIVAL_KINDS.index(kind))
+    if kind == "bursty":
+        arrivals, t = [], 0
+        while len(arrivals) < n:
+            arrivals += [t] * int(rng.integers(2, 6))
+            t += int(rng.integers(4, 11))
+        arrivals = arrivals[:n]
+    else:
+        arrivals = np.cumsum(rng.poisson(2.0, n)).tolist()
+    if kind == "longtail":
+        plens = np.clip(rng.lognormal(1.5, 0.9, n), 1, max_prompt)
+        gens = np.clip(rng.lognormal(1.2, 0.8, n), 1, max_new)
+    else:
+        plens = rng.integers(1, max_prompt + 1, n)
+        gens = rng.integers(1, max_new + 1, n)
+    trace = []
+    for i, (t, p, g) in enumerate(zip(arrivals, plens, gens)):
+        knobs = (dict(temperature=0.8, top_p=0.9, seed=100 + i)
+                 if sampled and i % 3 == 0 else {})
+        trace.append((int(t), Request(prompt=rng.integers(1, 64, int(p)).tolist(),
+                                      max_new_tokens=int(g), **knobs)))
+    return trace
+
+
+def serve_arrivals(engine: Engine, trace, waves: bool = False):
+    """Serve ``trace`` from a reset engine, one ``step()`` per tick; return
+    (each request's tokens in trace order, ticks until all finished).
+
+    Continuous: each request is submitted at its arrival tick.  ``waves``:
+    arrived requests are held until the engine drains, then submitted
+    ``max_slots`` at a time (static batching)."""
+    engine.reset()
+    index, out, held = {}, {}, []
+    nxt = tick = 0
+    while nxt < len(trace) or held or engine.pending():
+        assert tick < 4000, "open-loop run did not drain"
+        while nxt < len(trace) and trace[nxt][0] <= tick:
+            held.append(nxt)
+            nxt += 1
+        if not waves or not engine.pending():
+            wave = held[:engine.scfg.max_slots] if waves else held
+            for j in wave:
+                index[engine.submit(trace[j][1])] = j
+            held = held[len(wave):]
+        if engine.pending():
+            for rid, toks in engine.step().items():
+                out[index[rid]] = toks
+        tick += 1
+    assert sorted(out) == list(range(len(trace)))    # every request finished
+    return [out[j] for j in range(len(trace))], tick
+
+
+def step_counters(engine: Engine) -> dict[str, int]:
+    stats = engine.stats()
+    return {k: stats[k] for k in STEP_COUNTERS}
+
+
+@functools.lru_cache(maxsize=None)
+def solo_arrival_tokens(make, family: str, kind: str, sampled: bool = False):
+    """Each trace request served alone on ``make(family)``'s engine."""
+    engine = make(family)
+    out = []
+    for _, r in arrival_trace(kind, sampled):
+        engine.reset()
+        out.append(engine.generate([r])[0])
+    return out
+
+
+@pytest.mark.parametrize("family", sorted(CONFIGS))
+@pytest.mark.parametrize("kind", ARRIVAL_KINDS)
+def test_open_loop_arrivals_serve_solo_tokens(kind, family):
+    """Requests submitted at their arrival steps while the engine steps all
+    finish with their solo tokens, and a second run of the trace repeats
+    the step counters exactly."""
+    engine = engine_for(family)
+    trace = arrival_trace(kind)
+    got, _ = serve_arrivals(engine, trace)
+    counters = step_counters(engine)
+    assert serve_arrivals(engine, trace)[0] == got
+    assert step_counters(engine) == counters
+    assert got == solo_arrival_tokens(engine_for, family, kind)
+
+
+@pytest.mark.parametrize("kind", ARRIVAL_KINDS)
+def test_open_loop_arrivals_with_decode_kernel(kind):
+    """The same contract on the routed engine: the paged Pallas decode
+    kernel, with pages retired and refilled as requests come and go."""
+    engine = routed_engine_for("dense")
+    trace = arrival_trace(kind)
+    got, _ = serve_arrivals(engine, trace)
+    counters = step_counters(engine)
+    stats = engine.stats()
+    assert stats["decode_attn_pallas_layers"] == CONFIGS["dense"].n_layers
+    assert stats["kv_pages_free"] == stats["kv_pages_total"]
+    assert counters["retires"] == len(trace)
+    assert serve_arrivals(engine, trace)[0] == got
+    assert step_counters(engine) == counters
+    assert got == solo_arrival_tokens(routed_engine_for, "dense", kind)
+
+
+@pytest.mark.parametrize("kind", ARRIVAL_KINDS)
+def test_open_loop_mixed_greedy_and_sampled(kind):
+    """A third of the requests sample, so the decode step's sampler branch
+    is taken on some steps and skipped on others; every request still
+    emits its solo tokens."""
+    engine = engine_for("dense")
+    trace = arrival_trace(kind, sampled=True)
+    got, _ = serve_arrivals(engine, trace)
+    counters = step_counters(engine)
+    assert 0 < counters["decode_sampled_steps"] < counters["decode_steps"]
+    assert got == solo_arrival_tokens(engine_for, "dense", kind, sampled=True)
+
+
+@pytest.mark.parametrize("kind", ARRIVAL_KINDS)
+def test_continuous_refill_beats_static_waves(kind):
+    """Continuous submission finishes each trace in fewer steps than static
+    waves of ``max_slots`` (submit a wave, drain it, repeat), with the same
+    tokens."""
+    engine = engine_for("dense")
+    trace = arrival_trace(kind)
+    continuous, continuous_ticks = serve_arrivals(engine, trace)
+    static, static_ticks = serve_arrivals(engine, trace, waves=True)
+    assert static == continuous
+    assert continuous_ticks < static_ticks
+
+
+# ---------------------------------------------------------------------------
+# Engine.stats() accounting
+# ---------------------------------------------------------------------------
+
+def test_engine_stats_accounting():
+    cfg = ModelConfig(name="stats-t", family="dense", n_layers=2, d_model=32,
+                      n_heads=4, n_kv_heads=2, d_ff=64, vocab=64, head_dim=8,
+                      scan_layers=False, remat=False)
+    params = init_model(jax.random.PRNGKey(0), cfg, permissive())
+    eng = Engine(cfg, permissive(), params,
+                 ServeConfig(max_slots=2, max_len=32, prefill_chunk=4))
+    s0 = eng.stats()
+    for k in ("params_bytes", "artifact_bytes", "slot_cache_bytes",
+              "live_bytes", "peak_live_bytes"):
+        assert s0[k] > 0, k
+    assert s0["queue_depth"] == 0 and s0["slots_active"] == 0
+    assert s0["prefill_bytes"] == 0
+    assert s0["peak_live_bytes"] == s0["live_bytes"]
+
+    # 3 requests into 2 slots: all queue until step() admits, then one is
+    # left waiting; peak must include the admitted slots' prefill caches
+    for _ in range(3):
+        eng.submit(Request(prompt=[1, 2, 3], max_new_tokens=4))
+    assert eng.stats()["queue_depth"] == 3
+    eng.step()
+    assert eng.stats()["queue_depth"] == 1
+    s1 = eng.stats()
+    assert s1["peak_live_bytes"] > s0["peak_live_bytes"]
+    while eng.pending():
+        eng.step()
+    s2 = eng.stats()
+    # drained: live falls back to the static floor, peak is sticky
+    assert s2["live_bytes"] == s0["live_bytes"]
+    assert s2["peak_live_bytes"] == s1["peak_live_bytes"]
+    assert s2["queue_depth"] == 0 and s2["slots_active"] == 0
+    # reset() rebases the peak
+    eng.reset()
+    assert eng.stats()["peak_live_bytes"] == s0["peak_live_bytes"]
 
 
 # ---------------------------------------------------------------------------
