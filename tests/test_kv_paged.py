@@ -3,9 +3,11 @@
 The contract, layered from structure up to behavior:
 
 - **Page bookkeeping** (no jax): the PageAllocator hands out lowest-id
-  pages deterministically, refuses double frees and foreign ids, and a
-  hypothesis property drives random alloc/release interleavings against
-  the conservation invariant (free + held == pool, no aliasing).
+  pages deterministically, in exactly the order of a plain sorted-list
+  reference, refuses double frees and foreign ids without changing any
+  state, and a hypothesis property drives random alloc/release
+  interleavings against the conservation invariant (free + held == pool,
+  no aliasing).
 - **Numerics**: the fused-scale decode attention (scales folded into q
   pre-dot / context post-dot, int8 operands in the dots) matches the
   dequantize-first f32 oracle to float tolerance, on both the XLA
@@ -16,7 +18,8 @@ The contract, layered from structure up to behavior:
   re-proved over the paged cache with page reuse in the mix); the FIRST
   emitted token of every request matches the monolithic f32 engine
   exactly (it is drawn from the f32 prefill logits in both layouts);
-  eviction returns every page (stats-visible) and admission is gated by
+  eviction returns every page (stats-visible), freed pages go out again
+  lowest first into the slots' page-table rows, and admission is gated by
   free pages, not just free slots; a paged int8 pool that holds no more
   bytes than a monolithic one runs strictly more slots at once.
 - **Bugfix satellites**: bucketed pad-and-mask prefill ≡ exact-length
@@ -98,6 +101,64 @@ def test_page_allocator_refuses_bad_releases():
         pa.release([99])
     with pytest.raises(RuntimeError, match="exhausted"):
         pa.alloc(5)
+
+
+class _SortedListPool:
+    """Plain reference allocator: a sorted list, lowest id handed out first."""
+
+    def __init__(self, n_pages: int):
+        self.free = list(range(n_pages))
+
+    def alloc(self, n: int) -> list[int]:
+        pages, self.free = self.free[:n], self.free[n:]
+        return pages
+
+    def release(self, pages: list[int]) -> None:
+        self.free = sorted(self.free + list(pages))
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_page_allocator_order_matches_sorted_list_reference(seed):
+    """Random alloc/release sequences hand out exactly the pages the plain
+    sorted-list reference hands out, call for call."""
+    rng = np.random.RandomState(seed)
+    n_pages = int(rng.randint(1, 200))
+    pa, ref = PageAllocator(n_pages), _SortedListPool(n_pages)
+    held: list[list[int]] = []
+    for _ in range(400):
+        if held and rng.rand() < 0.45:
+            pages = held.pop(int(rng.randint(len(held))))
+            pa.release(pages)
+            ref.release(pages)
+        else:
+            want = int(rng.randint(1, max(2, n_pages // 4)))
+            if pa.can_alloc(want):
+                pages = pa.alloc(want)
+                assert pages == ref.alloc(want)
+                held.append(pages)
+        assert pa.n_free == len(ref.free)
+        assert sorted(pa.free) == ref.free
+
+
+@pytest.mark.parametrize("bad, match", [
+    ([0, 0], "double free"),            # a held page twice
+    ([0, 2, 2], "double free"),         # duplicate after a valid page
+    ([0, 5], "double free"),            # a page that is already free
+    ([0, 8], "outside pool"),           # past the end of the pool
+    ([0, -1], "outside pool"),          # negative id
+])
+def test_page_allocator_failed_release_changes_nothing(bad, match):
+    """A refused release mutates nothing: the held pages before the bad id
+    stay held, ``n_free`` is unchanged, and the next alloc is unchanged."""
+    pa = PageAllocator(8)
+    assert pa.alloc(5) == [0, 1, 2, 3, 4]
+    pa.release([1, 3])                                  # free: 1 3 5 6 7
+    with pytest.raises(ValueError, match=match):
+        pa.release(bad)
+    assert pa.n_free == 5
+    assert pa.alloc(5) == [1, 3, 5, 6, 7]
+    pa.release([0, 2])            # still held, so they release cleanly
+    assert pa.alloc(2) == [0, 2]
 
 
 try:                     # optional dev dependency — only these tests skip
@@ -291,6 +352,63 @@ def test_admission_gated_by_free_pages_not_just_slots():
     # the pool bound is enforced at submit for impossible requests
     with pytest.raises(ValueError, match="kv_pages"):
         engine.submit(Request(prompt=list(range(1, 40)), max_new_tokens=20))
+
+
+class _RecordingAllocator(PageAllocator):
+    """PageAllocator that logs every alloc and release, in call order."""
+
+    def __init__(self, n_pages: int):
+        super().__init__(n_pages)
+        self.log: list[tuple[str, list[int]]] = []
+
+    def alloc(self, n: int) -> list[int]:
+        pages = super().alloc(n)
+        self.log.append(("alloc", pages))
+        return pages
+
+    def release(self, pages: list[int]) -> None:
+        super().release(pages)
+        self.log.append(("release", list(pages)))
+
+
+def test_engine_reuses_freed_pages_lowest_first():
+    """Mixed-length requests over 3 slots retire and re-admit mid-stream:
+    every slot receives the lowest free pages (replayed on the sorted-list
+    reference), its page-table row holds exactly those pages, freed pages
+    are handed out again, and the tokens equal the batch-1 runs."""
+    engine = engine_for("dense")
+    rng = np.random.RandomState(7)
+    reqs = [Request(prompt=rng.randint(1, 64, int(n)).tolist(),
+                    max_new_tokens=int(m))
+            for n, m in zip(rng.randint(2, 30, 9), rng.randint(2, 24, 9))]
+    solo = []
+    for r in reqs:
+        engine.reset()
+        solo.append(engine.generate([r])[0])
+
+    engine.reset()
+    kv = engine._kv
+    engine._pager = rec = _RecordingAllocator(kv.n_pages)
+    rids = [engine.submit(r) for r in reqs]
+    collected = {}
+    while engine.pending():
+        collected.update(engine.step())
+        pt = np.asarray(engine.cache["pt"])
+        for slot, pages in engine._slot_pages.items():
+            assert pt[slot].tolist() == pages + [kv.trash_page] * (
+                kv.max_pages_per_slot - len(pages))
+    assert [collected[rid] for rid in rids] == solo
+    assert engine.stats()["kv_pages_free"] == kv.n_pages
+
+    ref, released, reused = _SortedListPool(kv.n_pages), set(), False
+    for op, pages in rec.log:
+        if op == "alloc":
+            assert pages == ref.alloc(len(pages))
+            reused |= bool(released & set(pages))
+        else:
+            ref.release(pages)
+            released |= set(pages)
+    assert reused                    # freed pages were handed out again
 
 
 @pytest.mark.parametrize("family", sorted(CONFIGS))
